@@ -202,6 +202,9 @@ def main(argv=None) -> int:
                          "ticks) to a JSONL file")
     args = ap.parse_args(argv)
 
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     import jax
     from repro import obs
     from repro.configs import get_config, reduce_for_smoke

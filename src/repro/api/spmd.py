@@ -71,13 +71,13 @@ import textwrap
 from typing import Mapping
 
 import jax
+from jax.extend import core as jax_core
 from jax.sharding import PartitionSpec as P
 
 from repro.api import context as context_lib
 from repro.obs import bus as obs_bus
 from repro.obs import events as obs_events
 from repro.parallel import rules as rules_lib
-from repro.parallel.shardmap_compat import NO_CHECK, inside_shard_map, shard_map
 
 __all__ = ["Partitioning", "SCALAR", "replicated", "partitioning_for",
            "spmd_mesh", "spmd_launch", "ShardContext", "shard_specs",
@@ -330,6 +330,12 @@ def spmd_mesh(ctx: "context_lib.PlanContext | None" = None):
     return mesh
 
 
+def inside_shard_map() -> bool:
+    """True under an active mapped trace: a shard_map or pmap body binds
+    its mesh axis names into the axis environment, plain jit does not."""
+    return bool(jax.core.nonempty_axis_env_DO_NOT_USE())
+
+
 _FALLBACK_LOGGED: set[tuple] = set()
 
 
@@ -418,8 +424,8 @@ def spmd_launch(entry, mesh, arrays, scalars):
                     out = jax.lax.psum(out, reduce_axes)
             return out
 
-    fn = shard_map(_shard_body, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_spec, **NO_CHECK)
+    fn = jax.shard_map(_shard_body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_spec, check_vma=False)
     return fn(*arrays)
 
 
@@ -512,7 +518,7 @@ def _flatten_rows(jaxpr, var_ids, rows, counter):
         return counter[0]
 
     def vid(v, make=False):
-        if isinstance(v, jax.core.Literal):
+        if isinstance(v, jax_core.Literal):
             return None
         if v not in var_ids:
             if not make:

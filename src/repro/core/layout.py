@@ -27,7 +27,14 @@ from typing import Mapping
 LANES = 128          # minor-most dim of a VREG tile / MXU systolic edge
 SUBLANES = 8         # second-minor dim of a VREG tile (fp32); bf16 packs 16
 MXU_EDGE = 128       # MXU matmul tile edge
-VMEM_BYTES = 128 * 1024 * 1024 // 8  # ~16 MiB usable VMEM per core (v5e)
+# The scoped VMEM limit every pallas_call compiles under (v5e has 128 MiB of
+# VMEM per core; Mosaic's own default limit is 16 MiB).  Half of it is the
+# block chooser's budget for pipelined buffers -- Pallas keeps
+# PIPELINE_DEPTH copies of every blocked operand -- and the other half holds
+# the temporaries a kernel body materialises (fp32 upcasts, rolled views).
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+VMEM_BYTES = VMEM_LIMIT_BYTES // 2
+PIPELINE_DEPTH = 2
 
 
 def round_up(n: int, multiple: int) -> int:
@@ -170,14 +177,15 @@ def choose_block_shape(
     The paper's rule "align each segment to the controller period" becomes:
     the block minor dim is a multiple of 128 lanes (full lines per DMA), the
     block major dim a multiple of ``sublane_tile`` sublanes (8 for fp32, 16
-    for 2-byte dtypes, 32 for fp8), and ``n_buffers`` blocks (double-buffered
-    in/out streams) must fit the VMEM budget.  Kernels that stream full-width
-    row blocks pass ``max_block_cols=cols`` so the row budget is charged
-    against the columns they actually keep resident.
+    for 2-byte dtypes, 32 for fp8), and ``n_buffers`` blocked operands, each
+    held ``PIPELINE_DEPTH`` times by the Pallas pipeline, must fit the VMEM
+    budget.  Kernels that stream full-width row blocks pass
+    ``max_block_cols=cols`` so the row budget is charged against the columns
+    they actually keep resident.
     """
     bcols = round_up(min(cols, max_block_cols), LANES)
     # rows: as many sublane-multiples as fit the budget
-    per_row = bcols * bytes_per_el * n_buffers
+    per_row = bcols * bytes_per_el * n_buffers * PIPELINE_DEPTH
     brows = max(sublane_tile, round_down(
         min(vmem_budget // max(per_row, 1), max_block_rows, rows),
         sublane_tile,

@@ -39,6 +39,7 @@ from repro.core.aliasing import InterleavedMemoryModel, Stream
 from repro.core.autotune import LayoutPlan, StreamSignature, plan_streams
 from repro.core.layout import (
     LANES,
+    PIPELINE_DEPTH,
     SUBLANES,
     VMEM_BYTES,
     cdiv,
@@ -784,8 +785,13 @@ def _plan_lbm(kernel: str, shape: tuple[int, ...], sig: StreamSignature,
               budget: int) -> tuple[tuple[int, ...], tuple[int, ...]]:
     """D3Q19 collision layouts.  ``shape`` is the lattice (Q, X, Y, Z).
 
-    soa : f stored (Q, S)        -- block (Q, bs), bs sized so 2 buffers of
-                                    all Q direction rows fit VMEM.
+    soa : f stored (Q, S)        -- block (Q, bs), bs sized so the in and
+                                    out blocks of all Q direction rows fit
+                                    VMEM, PIPELINE_DEPTH copies each.  A
+                                    block narrower than the lattice holds
+                                    whole (sublanes, 128) tiles of sites,
+                                    because the kernel views each direction
+                                    row as (bs/128, 128).
     ivjk: f stored (S/128, Q, L) -- directions interleaved at lane
                                     granularity; block is bsb super-rows.
     """
@@ -797,16 +803,20 @@ def _plan_lbm(kernel: str, shape: tuple[int, ...], sig: StreamSignature,
         s *= int(d)
     s = max(s, 1)
     elem = sig.elem_bytes
+    buffers = 2 * PIPELINE_DEPTH          # in + out, each pipelined
     if kernel == "lbm.soa":
-        cap = round_down(
-            min(budget // max(q * elem * 2, 1), MAX_WIDTH), LANES
-        )
-        bs = max(min(cap, round_up(s, LANES)), LANES)
+        tile = LANES * sublanes
+        cap = max(round_down(
+            min(budget // max(q * elem * buffers, 1), MAX_WIDTH), tile
+        ), tile)
+        bs = round_up(s, LANES)
+        if bs > cap:
+            bs = cap
         spad = round_up(s, bs)
         return (q, spad), (q, bs)
     # ivjk: super-block rows of (Q, 128) slabs
     cap = round_down(
-        min(budget // max(q * LANES * elem * 2, 1), 64), sublanes
+        min(budget // max(q * LANES * elem * buffers, 1), 64), sublanes
     )
     bsb = max(min(cap, round_up(cdiv(s, LANES), sublanes)), sublanes)
     spad = round_up(s, bsb * LANES)

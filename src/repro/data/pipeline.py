@@ -43,28 +43,22 @@ def _tokens_for(cfg: DataConfig, step: int, rows: np.ndarray) -> np.ndarray:
 
 
 def make_batch(cfg: DataConfig, step: int, sharding=None) -> dict:
-    """Global batch for ``step`` (host-sharded when a sharding is given)."""
+    """Global batch for ``step`` (host-sharded when a sharding is given).
 
-    def tokens_cb(index) -> np.ndarray:
-        rows = np.arange(cfg.global_batch)[index[0]]
-        block = _tokens_for(cfg, step, rows)
-        cols = index[1] if len(index) > 1 else slice(None)
-        return block[:, :-1][:, cols]
-
-    def labels_cb(index) -> np.ndarray:
-        rows = np.arange(cfg.global_batch)[index[0]]
-        block = _tokens_for(cfg, step, rows)
-        cols = index[1] if len(index) > 1 else slice(None)
-        return block[:, 1:][:, cols]
-
+    The token ids of the whole global batch are drawn in one go and each
+    shard is cut from them, so a sharded batch holds exactly the tokens of
+    the unsharded one (the ids are a few KiB; the draws depend on how many
+    rows are drawn at once)."""
+    full = _tokens_for(cfg, step, np.arange(cfg.global_batch))
     shape = (cfg.global_batch, cfg.seq_len)
     if sharding is not None:
         batch = {
-            "tokens": jax.make_array_from_callback(shape, sharding, tokens_cb),
-            "labels": jax.make_array_from_callback(shape, sharding, labels_cb),
+            "tokens": jax.make_array_from_callback(
+                shape, sharding, lambda index: full[:, :-1][index]),
+            "labels": jax.make_array_from_callback(
+                shape, sharding, lambda index: full[:, 1:][index]),
         }
     else:
-        full = _tokens_for(cfg, step, np.arange(cfg.global_batch))
         batch = {
             "tokens": jnp.asarray(full[:, :-1]),
             "labels": jnp.asarray(full[:, 1:]),

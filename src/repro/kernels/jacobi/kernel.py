@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.util import INTERPRET, block_rows
+from repro.kernels.util import block_rows, compiler_params, interpret
 
 
 def _jacobi_kernel(sa_ref, sb_ref, sl_ref, out_ref, *, n_cols: int):
@@ -59,5 +59,6 @@ def jacobi_rows(
         in_specs=[spec, spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((rows, width), sl.dtype),
-        interpret=INTERPRET,
+        compiler_params=compiler_params("parallel"),
+        interpret=interpret(),
     )(sa, sb, sl)

@@ -23,81 +23,119 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.layout import LANES
 from repro.kernels.lbm.ref import C, Q, W
-from repro.kernels.util import INTERPRET
+from repro.kernels.util import compiler_params, interpret
 
 
-def _collide_block(f: jax.Array, c: jax.Array, w: jax.Array, omega: jax.Array,
-                   v_axis: int) -> jax.Array:
-    """BGK collision with the direction axis at ``v_axis``."""
-    dt = f.dtype
-    rho = jnp.sum(f, axis=v_axis, keepdims=True)
-    mom = jnp.tensordot(f, c, axes=(v_axis, 0))          # (..., 3), v axis gone
-    mom = jnp.moveaxis(mom, -1, v_axis)                  # (..., 3 at v_axis, ...)
-    u = mom / rho
-    cu = jnp.tensordot(u, c, axes=(v_axis, 1))           # (..., Q)
-    cu = jnp.moveaxis(cu, -1, v_axis)
-    usq = jnp.sum(u * u, axis=v_axis, keepdims=True)
-    shape = [1] * f.ndim
-    shape[v_axis] = Q
-    wb = w.reshape(shape)
+def _signed_sum(terms):
+    """Sum of (sign, array) terms in the given order."""
+    sign, acc = terms[0]
+    acc = acc if sign > 0 else -acc
+    for sign, a in terms[1:]:
+        acc = acc + a if sign > 0 else acc - a
+    return acc
+
+
+def _equilibrium(fs: list, coef: list, dt) -> jax.Array:
+    """D3Q19 equilibrium of a whole block.
+
+    ``fs`` are the 19 per-direction slices of the block, each keeping the
+    direction axis at size 1; ``coef`` are the (c_x, c_y, c_z, w) tables,
+    shaped to broadcast along the direction axis.  Density and momentum
+    sum the directions in order (exact sign selections, no products), and
+    the equilibrium is one whole-block expression: one store per block, so
+    the arithmetic of a site is the same for every block shape."""
+    rho = _signed_sum([(1, f) for f in fs])
+    u = [_signed_sum([(int(C[v][a]), fs[v]) for v in range(Q) if C[v][a]])
+         / rho for a in range(3)]
+    usq = u[0] * u[0] + u[1] * u[1] + u[2] * u[2]
+    cx, cy, cz, w = coef
+    cu = cx * u[0] + cy * u[1] + cz * u[2]
     one, three, f45, f15 = (jnp.asarray(v, dt) for v in (1.0, 3.0, 4.5, 1.5))
-    feq = wb * rho * (one + three * cu + f45 * cu * cu - f15 * usq)
-    return f - omega * (f - feq)
+    return w * rho * (one + three * cu + f45 * cu * cu - f15 * usq)
 
 
-def _soa_kernel(f_ref, c_ref, w_ref, om_ref, o_ref):
-    o_ref[...] = _collide_block(
-        f_ref[...], c_ref[...], w_ref[...], om_ref[0], v_axis=0
-    )
+def _soa_kernel(om_ref, co_ref, f_ref, o_ref):
+    # block (Q, rows, 128): each direction is a dense (rows, 128) tile;
+    # coefficients (4, Q, 1, 128)
+    dt = o_ref.dtype
+    omega = om_ref[0].astype(dt)
+    feq = _equilibrium([f_ref[v:v + 1] for v in range(Q)],
+                       [co_ref[a] for a in range(4)], dt)
+    f = f_ref[...]
+    o_ref[...] = f - omega * (f - feq)
 
 
-def _ivjk_kernel(f_ref, c_ref, w_ref, om_ref, o_ref):
-    o_ref[...] = _collide_block(
-        f_ref[...], c_ref[...], w_ref[...], om_ref[0], v_axis=1
-    )
+def _ivjk_kernel(om_ref, co_ref, f_ref, o_ref):
+    # block (bsb, Q, 128): direction v is the strided (bsb, 1, 128) slice;
+    # coefficients (4, 1, Q, 128)
+    dt = o_ref.dtype
+    omega = om_ref[0].astype(dt)
+    feq = _equilibrium([f_ref[:, v:v + 1, :] for v in range(Q)],
+                       [co_ref[a] for a in range(4)], dt)
+    f = f_ref[...]
+    o_ref[...] = f - omega * (f - feq)
 
 
-def _const_args(dtype, omega):
-    """The D3Q19 constants as kernel operands (Pallas kernels may not
-    capture array constants)."""
-    return (
-        jnp.asarray(C, dtype),
-        jnp.asarray(W, dtype),
-        jnp.asarray([omega], dtype),
-    )
+def _omega(omega) -> jax.Array:
+    """omega as the kernels' SMEM scalar operand (32-bit: SMEM words)."""
+    return jnp.reshape(jnp.asarray(omega, jnp.float32), (1,))
 
 
-_CONST_SPECS = [pl.BlockSpec(memory_space=pl.ANY)] * 3
+def _coefficients(dtype, shape: tuple[int, ...]) -> jax.Array:
+    """The (c_x, c_y, c_z, w) tables of the 19 directions as one VMEM
+    operand of ``shape`` (4, then the direction axis where the layout
+    keeps it, lanes last); a Pallas body may not capture array constants.
+    The block index never changes, so it is fetched once per launch."""
+    table = np.concatenate([C.T, W[None]]).astype(np.float32)    # (4, Q)
+    table = table.reshape([4] + [Q if d == Q else 1 for d in shape[1:]])
+    return jnp.asarray(np.broadcast_to(table, shape), dtype)
+
+
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _whole(shape: tuple[int, ...]) -> pl.BlockSpec:
+    return pl.BlockSpec(shape, lambda i: (0,) * len(shape))
 
 
 def collide_soa(f: jax.Array, omega: float, *, bs: int = 2048) -> jax.Array:
-    """f: (Q, S) with S a multiple of bs (bs a lane multiple)."""
+    """f: (Q, S) with S a multiple of bs (bs a lane multiple; a block
+    narrower than S holds whole (sublane, 128) tiles of sites)."""
     q, s = f.shape
-    assert q == Q and s % bs == 0, (q, s, bs)
-    spec = pl.BlockSpec((Q, bs), lambda i: (0, i))
-    return pl.pallas_call(
+    assert q == Q and s % bs == 0 and bs % LANES == 0, (q, s, bs)
+    spec = pl.BlockSpec((Q, bs // LANES, LANES), lambda i: (0, i, 0))
+    coef = (4, Q, 1, LANES)
+    out = pl.pallas_call(
         _soa_kernel,
         grid=(s // bs,),
-        in_specs=[spec, *_CONST_SPECS],
+        in_specs=[_SMEM, _whole(coef), spec],
         out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((q, s), f.dtype),
-        interpret=INTERPRET,
-    )(f, *_const_args(f.dtype, omega))
+        out_shape=jax.ShapeDtypeStruct((q, s // LANES, LANES), f.dtype),
+        compiler_params=compiler_params("parallel"),
+        interpret=interpret(),
+    )(_omega(omega), _coefficients(f.dtype, coef),
+      f.reshape(q, s // LANES, LANES))
+    return out.reshape(q, s)
 
 
 def collide_ivjk(f: jax.Array, omega: float, *, bsb: int = 16) -> jax.Array:
     """f: (S/128, Q, 128) with the super-block count a multiple of bsb."""
     sb, q, lanes = f.shape
-    assert q == Q and lanes == 128 and sb % bsb == 0, (f.shape, bsb)
+    assert q == Q and lanes == LANES and sb % bsb == 0, (f.shape, bsb)
     spec = pl.BlockSpec((bsb, Q, lanes), lambda i: (i, 0, 0))
+    coef = (4, 1, Q, LANES)
     return pl.pallas_call(
         _ivjk_kernel,
         grid=(sb // bsb,),
-        in_specs=[spec, *_CONST_SPECS],
+        in_specs=[_SMEM, _whole(coef), spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(f.shape, f.dtype),
-        interpret=INTERPRET,
-    )(f, *_const_args(f.dtype, omega))
+        compiler_params=compiler_params("parallel"),
+        interpret=interpret(),
+    )(_omega(omega), _coefficients(f.dtype, coef), f)

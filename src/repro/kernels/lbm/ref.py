@@ -27,13 +27,18 @@ C = np.array(
 W = np.array([1 / 3] + [1 / 18] * 6 + [1 / 36] * 12, dtype=np.float64)
 Q = 19
 
+# The oracle's contractions run at full precision: a TPU otherwise rounds
+# fp32 matmul operands to bf16, three orders of magnitude coarser than the
+# kernels' elementwise fp32 arithmetic.
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 def equilibrium(rho: jax.Array, u: jax.Array) -> jax.Array:
     """f_eq[v, ...] for density rho[...] and velocity u[3, ...]."""
     dt = rho.dtype
     c = jnp.asarray(C, dt)          # (Q, 3)
     w = jnp.asarray(W, dt)          # (Q,)
-    cu = jnp.tensordot(c, u, axes=(1, 0))            # (Q, ...)
+    cu = jnp.tensordot(c, u, axes=(1, 0), precision=_EXACT)  # (Q, ...)
     usq = jnp.sum(u * u, axis=0)                     # (...)
     one, three, f45, f15 = (jnp.asarray(v, dt) for v in (1.0, 3.0, 4.5, 1.5))
     return w.reshape((Q,) + (1,) * rho.ndim) * rho * (
@@ -45,7 +50,7 @@ def moments(f: jax.Array) -> tuple[jax.Array, jax.Array]:
     """(rho, u) from f[v, ...]."""
     rho = jnp.sum(f, axis=0)
     c = jnp.asarray(C, f.dtype)
-    mom = jnp.tensordot(c.T, f, axes=(1, 0))         # (3, ...)
+    mom = jnp.tensordot(c.T, f, axes=(1, 0), precision=_EXACT)  # (3, ...)
     return rho, mom / rho
 
 

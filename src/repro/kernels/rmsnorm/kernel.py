@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.util import INTERPRET, block_rows
+from repro.kernels.util import block_rows, compiler_params, interpret
 
 
 def _rms(x: jax.Array, d_logical: int, eps: float) -> jax.Array:
@@ -44,15 +44,18 @@ def _gated_kernel(x_ref, z_ref, s_ref, o_ref, *, d_logical: int, eps: float):
 def _call(kernel, args, rows, width, dtype, brows):
     brows = brows or block_rows(rows)
     spec = pl.BlockSpec((brows, width), lambda i: (i, 0))
-    svec = pl.BlockSpec((width,), lambda i: (0,))
+    # the scale vector rides as one (1, width) row, resident across the grid
+    svec = pl.BlockSpec((1, width), lambda i: (0, 0))
     in_specs = [spec] * (len(args) - 1) + [svec]
+    args = [*args[:-1], args[-1].reshape(1, width)]
     return pl.pallas_call(
         kernel,
         grid=(rows // brows,),
         in_specs=in_specs,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((rows, width), dtype),
-        interpret=INTERPRET,
+        compiler_params=compiler_params("parallel"),
+        interpret=interpret(),
     )(*args)
 
 

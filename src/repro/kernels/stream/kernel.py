@@ -14,8 +14,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.util import INTERPRET, block_rows
+from repro.kernels.util import block_rows, compiler_params, interpret
 
 
 def _copy_kernel(a_ref, c_ref):
@@ -23,7 +24,7 @@ def _copy_kernel(a_ref, c_ref):
 
 
 def _scale_kernel(c_ref, s_ref, b_ref):
-    b_ref[...] = s_ref[0] * c_ref[...]
+    b_ref[...] = s_ref[0].astype(b_ref.dtype) * c_ref[...]
 
 
 def _add_kernel(a_ref, b_ref, c_ref):
@@ -31,7 +32,7 @@ def _add_kernel(a_ref, b_ref, c_ref):
 
 
 def _triad_kernel(b_ref, c_ref, s_ref, a_ref):
-    a_ref[...] = b_ref[...] + s_ref[0] * c_ref[...]
+    a_ref[...] = b_ref[...] + s_ref[0].astype(a_ref.dtype) * c_ref[...]
 
 
 def _call(kernel, inputs, scalar, out_dtype, *, brows=None):
@@ -42,15 +43,18 @@ def _call(kernel, inputs, scalar, out_dtype, *, brows=None):
     in_specs = [spec] * len(inputs)
     args = list(inputs)
     if scalar is not None:
-        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-        args.append(jnp.asarray([scalar], dtype=out_dtype))
+        # The scalar lives in SMEM as a 32-bit word and is cast to the
+        # stream dtype in the body.
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        args.append(jnp.reshape(jnp.asarray(scalar, jnp.float32), (1,)))
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((rows, width), out_dtype),
-        interpret=INTERPRET,
+        compiler_params=compiler_params("parallel"),
+        interpret=interpret(),
     )(*args)
 
 

@@ -20,7 +20,7 @@ from __future__ import annotations
 import jax
 from jax.experimental import pallas as pl
 
-from repro.kernels.util import INTERPRET, block_rows
+from repro.kernels.util import block_rows, compiler_params, interpret
 
 
 def _triad_kernel(b_ref, c_ref, d_ref, a_ref):
@@ -37,5 +37,6 @@ def triad2d(b: jax.Array, c: jax.Array, d: jax.Array, *, brows: int | None = Non
         in_specs=[spec, spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((rows, width), b.dtype),
-        interpret=INTERPRET,
+        compiler_params=compiler_params("parallel"),
+        interpret=interpret(),
     )(b, c, d)

@@ -10,12 +10,31 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.layout import LANES, SUBLANES, cdiv, round_up
+from repro.core.layout import LANES, SUBLANES, VMEM_LIMIT_BYTES, cdiv, round_up
 from repro.core.planner import KernelPlan
 
-# interpret=True on CPU; real TPUs compile the same kernels natively.
-INTERPRET = jax.default_backend() == "cpu"
+# Interpret mode is decided each time a kernel is traced, never at import:
+# ``None`` follows the default backend (the Pallas interpreter on the CPU,
+# native Mosaic on a TPU); a bool forces the choice -- a test that compiles
+# for a described TPU from a CPU process sets it to False.
+INTERPRET: bool | None = None
+
+
+def interpret() -> bool:
+    """Whether a ``pallas_call`` traced now runs in the Pallas interpreter."""
+    if INTERPRET is not None:
+        return INTERPRET
+    return jax.default_backend() == "cpu"
+
+
+def compiler_params(*semantics: str) -> pltpu.CompilerParams:
+    """Mosaic parameters every kernel compiles under: the one scoped-VMEM
+    limit the planner budgets against (``core.layout.VMEM_LIMIT_BYTES``)
+    and, per grid axis, "parallel" or "arbitrary" (a carried reduction)."""
+    return pltpu.CompilerParams(dimension_semantics=semantics or None,
+                                vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def to_tiles(x: jax.Array, width: int | None = None, *,

@@ -19,7 +19,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.util import INTERPRET
+from repro.kernels.util import compiler_params, interpret
+
+# Token-indexed operands (labels, per-token outputs, the running statistics)
+# are (T, 1) columns: a rank-1 block would have to be a multiple of the
+# 128-lane tiling and would carry a different HBM layout than XLA's.
 
 
 def _xent_kernel(lab_ref, lg_ref, out_ref, m_ref, l_ref, ll_ref, *,
@@ -35,14 +39,15 @@ def _xent_kernel(lab_ref, lg_ref, out_ref, m_ref, l_ref, ll_ref, *,
     x = lg_ref[...].astype(jnp.float32)                    # (bt, bv)
     col = j * bv + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
     x = jnp.where(col < logical_v, x, -1e30)
-    m_old = m_ref[...]
-    m_new = jnp.maximum(m_old, jnp.max(x, axis=-1))
-    p = jnp.where(x <= -1e29, 0.0, jnp.exp(x - m_new[:, None]))
-    l_ref[...] = l_ref[...] * jnp.exp(m_old - m_new) + jnp.sum(p, axis=-1)
+    m_old = m_ref[...]                                     # (bt, 1)
+    m_new = jnp.maximum(m_old, jnp.max(x, axis=-1, keepdims=True))
+    p = jnp.where(x <= -1e29, 0.0, jnp.exp(x - m_new))
+    l_ref[...] = l_ref[...] * jnp.exp(m_old - m_new) + jnp.sum(
+        p, axis=-1, keepdims=True)
     m_ref[...] = m_new
-    lab = lab_ref[...]                                     # (bt,)
+    lab = lab_ref[...]                                     # (bt, 1)
     ll_ref[...] = ll_ref[...] + jnp.sum(
-        jnp.where(col == lab[:, None], x, 0.0), axis=-1
+        jnp.where(col == lab, x, 0.0), axis=-1, keepdims=True
     )
 
     @pl.when(j == nv - 1)
@@ -59,10 +64,10 @@ def _xent_partial_kernel(off_ref, lab_ref, lg_ref, m_out, l_out, ll_out,
     Identical fold to ``_xent_kernel``, but the final vocab tile emits the
     running (max, sumexp, label-logit) instead of the finished NLL -- the
     cross-shard lse combine (pmax/psum over the mesh's vocab axis) happens
-    in the shard_map body that launched us.  ``off_ref`` holds this shard's
-    global column offset (traced: it comes from ``axis_index``), so masking
-    against the *global* logical vocab and the label match both work on
-    local column indices: global col = local col + off.
+    in the shard_map body that launched us.  ``off_ref`` (SMEM) holds this
+    shard's global column offset (traced: it comes from ``axis_index``), so
+    masking against the *global* logical vocab and the label match both
+    work on local column indices: global col = local col + off.
     """
     j = pl.program_id(1)
 
@@ -80,16 +85,18 @@ def _xent_partial_kernel(off_ref, lab_ref, lg_ref, m_out, l_out, ll_out,
     valid = (col < vl) & (col + off < logical_v)
     x = jnp.where(valid, x, -1e30)
     m_old = m_ref[...]
-    m_new = jnp.maximum(m_old, jnp.max(x, axis=-1))
-    p = jnp.where(x <= -1e29, 0.0, jnp.exp(x - m_new[:, None]))
-    l_ref[...] = l_ref[...] * jnp.exp(m_old - m_new) + jnp.sum(p, axis=-1)
+    m_new = jnp.maximum(m_old, jnp.max(x, axis=-1, keepdims=True))
+    p = jnp.where(x <= -1e29, 0.0, jnp.exp(x - m_new))
+    l_ref[...] = l_ref[...] * jnp.exp(m_old - m_new) + jnp.sum(
+        p, axis=-1, keepdims=True)
     m_ref[...] = m_new
-    lab = lab_ref[...]                                     # (bt,)
+    lab = lab_ref[...]                                     # (bt, 1)
     # The label match must stay inside the valid columns: a *padded* local
     # column's global index (col + off) can alias another shard's label
     # range, and matching there would fold the -1e30 mask into ll.
     ll_ref[...] = ll_ref[...] + jnp.sum(
-        jnp.where(valid & (col + off == lab[:, None]), x, 0.0), axis=-1
+        jnp.where(valid & (col + off == lab), x, 0.0), axis=-1,
+        keepdims=True
     )
 
     @pl.when(j == nv - 1)
@@ -97,6 +104,13 @@ def _xent_partial_kernel(off_ref, lab_ref, lg_ref, m_out, l_out, ll_out,
         m_out[...] = m_ref[...]
         l_out[...] = l_ref[...]
         ll_out[...] = ll_ref[...]
+
+
+def _column_specs(bt: int, bv: int):
+    """(labels column, logits tile, per-token column) block specs."""
+    return (pl.BlockSpec((bt, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((bt, bv), lambda i, j: (i, j)),
+            pl.BlockSpec((bt, 1), lambda i, j: (i, 0)))
 
 
 def xent_partial_tiled(logits: jax.Array, labels: jax.Array,
@@ -109,29 +123,25 @@ def xent_partial_tiled(logits: jax.Array, labels: jax.Array,
     *global* labels, offset: (1,) int32 global column offset of this shard;
     ``vl`` is the shard's logical vocab width (<= Vp), ``logical_v`` the
     *global* logical vocab.  T % bt == 0, Vp % bv == 0 (ops.py pads).
+    Returns three (T,) fp32 vectors.
     """
     t, v = logits.shape
     assert t % bt == 0 and v % bv == 0, (logits.shape, bt, bv)
     nt, nv = t // bt, v // bv
-    out = jax.ShapeDtypeStruct((t,), jnp.float32)
-    return pl.pallas_call(
+    lab_spec, lg_spec, col_spec = _column_specs(bt, bv)
+    out = jax.ShapeDtypeStruct((t, 1), jnp.float32)
+    m, l, ll = pl.pallas_call(
         functools.partial(_xent_partial_kernel, nv=nv, bv=bv, vl=vl,
                           logical_v=logical_v),
         grid=(nt, nv),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i, j: (0,)),
-            pl.BlockSpec((bt,), lambda i, j: (i,)),
-            pl.BlockSpec((bt, bv), lambda i, j: (i, j)),
-        ],
-        out_specs=[pl.BlockSpec((bt,), lambda i, j: (i,))] * 3,
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), lab_spec, lg_spec],
+        out_specs=[col_spec] * 3,
         out_shape=[out, out, out],
-        scratch_shapes=[
-            pltpu.VMEM((bt,), jnp.float32),
-            pltpu.VMEM((bt,), jnp.float32),
-            pltpu.VMEM((bt,), jnp.float32),
-        ],
-        interpret=INTERPRET,
-    )(offset, labels, logits)
+        scratch_shapes=[pltpu.VMEM((bt, 1), jnp.float32)] * 3,
+        compiler_params=compiler_params("parallel", "arbitrary"),
+        interpret=interpret(),
+    )(offset, labels.reshape(t, 1), logits)
+    return m[:, 0], l[:, 0], ll[:, 0]
 
 
 def xent_tiled(logits: jax.Array, labels: jax.Array, *, logical_v: int,
@@ -141,19 +151,15 @@ def xent_tiled(logits: jax.Array, labels: jax.Array, *, logical_v: int,
     t, v = logits.shape
     assert t % bt == 0 and v % bv == 0, (logits.shape, bt, bv)
     nt, nv = t // bt, v // bv
-    return pl.pallas_call(
+    lab_spec, lg_spec, col_spec = _column_specs(bt, bv)
+    nll = pl.pallas_call(
         functools.partial(_xent_kernel, nv=nv, bv=bv, logical_v=logical_v),
         grid=(nt, nv),
-        in_specs=[
-            pl.BlockSpec((bt,), lambda i, j: (i,)),
-            pl.BlockSpec((bt, bv), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((bt,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((t,), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((bt,), jnp.float32),
-            pltpu.VMEM((bt,), jnp.float32),
-            pltpu.VMEM((bt,), jnp.float32),
-        ],
-        interpret=INTERPRET,
-    )(labels, logits)
+        in_specs=[lab_spec, lg_spec],
+        out_specs=col_spec,
+        out_shape=jax.ShapeDtypeStruct((t, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bt, 1), jnp.float32)] * 3,
+        compiler_params=compiler_params("parallel", "arbitrary"),
+        interpret=interpret(),
+    )(labels.reshape(t, 1), logits)
+    return nll[:, 0]

@@ -154,7 +154,6 @@ def xent_grad(logits: jax.Array, labels: jax.Array, g: jax.Array, *,
         return vjp(g)[0]
 
     from repro.api.registry import resolve
-    from repro.parallel.shardmap_compat import NO_CHECK, shard_map
 
     g = jnp.asarray(g, jnp.float32)
     # Same partitioning as the registered forward (plus the replicated
@@ -189,8 +188,8 @@ def xent_grad(logits: jax.Array, labels: jax.Array, g: jax.Array, *,
         t_total = t * ctx.size(batch_axes)
         return ((p - onehot) * (gg / t_total)).astype(logits.dtype)
 
-    fn = shard_map(_grad_body, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_spec, **NO_CHECK)
+    fn = jax.shard_map(_grad_body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_spec, check_vma=False)
     return fn(logits, labels.astype(jnp.int32), g)
 
 

@@ -2,7 +2,10 @@
 generation against the arch's cache (KV / SSM state / mLSTM matrix state).
 
     PYTHONPATH=src python -m repro.launch.serve --arch zamba2-1.2b \
-        --mesh host --batch 4 --prompt-len 16 --gen 24
+        --mesh host --smoke --batch 4 --prompt-len 16 --gen 24
+
+--mesh host runs on one device at the config's published widths; --smoke
+shrinks the config for a CPU run.
 """
 from __future__ import annotations
 
@@ -15,6 +18,8 @@ def main() -> None:
     ap.add_argument("--arch", default="zamba2-1.2b")
     ap.add_argument("--mesh", choices=["host", "pod", "multipod"],
                     default="host")
+    ap.add_argument("--smoke", action="store_true",
+                    help="shrink the config to smoke size (reduce_for_smoke)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen", type=int, default=24)
@@ -25,6 +30,9 @@ def main() -> None:
                          " shapes -- see docs/SPMD.md)")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     import jax
     import jax.numpy as jnp
 
@@ -36,8 +44,9 @@ def main() -> None:
     from repro.parallel import steps as steps_lib
 
     cfg = get_config(args.arch)
-    if args.mesh == "host":
+    if args.smoke:
         cfg = reduce_for_smoke(cfg)
+    if args.mesh == "host":
         mesh = None
     else:
         mesh = make_production_mesh(multi_pod=(args.mesh == "multipod"))

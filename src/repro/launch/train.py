@@ -3,10 +3,11 @@
 Builds the mesh (or a host-local test mesh), applies the arch's layout
 policy and sharding rules, and runs the fault-tolerant trainer on the
 deterministic pipeline.  On a real pod this script is invoked once per host
-(JAX multi-process); in this container use --mesh host for a 1-device run.
+(JAX multi-process); --mesh host runs on one device at the config's
+published widths, and --smoke shrinks the config for a CPU run.
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b \
-        --mesh host --steps 20 --d-model 128 --layers 2
+        --mesh host --smoke --steps 20 --d-model 128 --layers 2
 """
 from __future__ import annotations
 
@@ -21,6 +22,8 @@ def main() -> None:
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--mesh", choices=["host", "pod", "multipod"],
                     default="host")
+    ap.add_argument("--smoke", action="store_true",
+                    help="shrink the config to smoke size (reduce_for_smoke)")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--global-batch", type=int, default=8)
@@ -44,6 +47,9 @@ def main() -> None:
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
 
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     import jax
 
     from repro import api
@@ -58,8 +64,9 @@ def main() -> None:
     from repro.runtime.trainer import Trainer, TrainerConfig
 
     cfg = get_config(args.arch)
-    if args.mesh == "host":
+    if args.smoke:
         cfg = reduce_for_smoke(cfg)
+    if args.mesh == "host":
         mesh = make_test_mesh((1, 1))
         tp = 1
     else:

@@ -38,17 +38,30 @@ def use_fused_kernels() -> bool:
     multi-device ``jax.sharding.Mesh``: ``api.launch`` then partitions the
     kernel over the mesh via shard_map using its registered
     ``Partitioning``, with each shard planning its own local block shape
-    (``repro.api.spmd``).  Without such a mesh -- or inside an existing
-    shard_map body (pipeline stages), or under ``plan_context(spmd=False)``
-    -- the pure-jnp path keeps the program partitionable, since a bare
-    ``pallas_call`` carries no partitioning rule.  The answer is resolved
-    at trace time, so one process can trace both paths under different
-    contexts."""
+    (``repro.api.spmd``).  A program with no mesh at all -- one chip of a
+    multi-chip host -- is a single-device program and launches them too.
+    Under a multi-device mesh that does not route through shard_map --
+    inside an existing shard_map body (pipeline stages), or under
+    ``plan_context(spmd=False)`` -- the pure-jnp path keeps the program
+    partitionable, since a bare ``pallas_call`` carries no partitioning
+    rule.  The answer is resolved at trace time, so one process can trace
+    both paths under different contexts."""
     if jax.device_count() == 1:
         return True
-    from repro.api import spmd  # lazy, mirroring the _rms_fused imports
+    from repro.api import context, spmd  # lazy, like the _rms_fused imports
+    from repro.parallel import rules
 
-    return spmd.spmd_mesh() is not None
+    if spmd.spmd_mesh() is not None:
+        return True
+    if spmd.inside_shard_map():
+        return False
+    # The mesh spmd_mesh() resolves; a {axis: size} mapping places nothing,
+    # so only no mesh or a one-device Mesh makes a single-device program.
+    mesh = context.current_context().mesh
+    if mesh is None:
+        mesh = rules.current_mesh()
+    return mesh is None or (isinstance(mesh, jax.sharding.Mesh)
+                            and mesh.size == 1)
 
 
 def _rms_ref(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import zlib
 from typing import Any, Callable
 
 import jax
@@ -65,14 +66,17 @@ def map_tree(fn: Callable[[ParamDef], Any], tree: Tree) -> Tree:
 
 
 def init_params(key: jax.Array, tree: Tree) -> Tree:
-    """Materialize every ParamDef with a key folded from its path hash."""
+    """Materialize every ParamDef with a key folded from a checksum of its
+    path (``hash`` of a str is salted per process, a checksum is not, so a
+    seed names the same parameters in every run)."""
 
     def rec(t: Tree, path: tuple[str, ...]) -> Tree:
         out = {}
         for k, v in t.items():
             p = path + (k,)
             if is_def(v):
-                sub = jax.random.fold_in(key, hash(p) & 0x7FFFFFFF)
+                tag = zlib.crc32("/".join(p).encode()) & 0x7FFFFFFF
+                sub = jax.random.fold_in(key, tag)
                 out[k] = v.materialize(sub)
             else:
                 out[k] = rec(v, p)

@@ -42,8 +42,12 @@ def init_state(params, cfg: AdamWConfig) -> dict:
         "v": jax.tree_util.tree_map_with_path(moment, params),
     }
     if cfg.master:
+        # Always a copy, never the param's own buffer (an fp32 astype is a
+        # no-op): the train step donates the whole state, and a buffer may
+        # be donated only once.
         state["master"] = jax.tree_util.tree_map_with_path(
-            lambda path, p: p.astype(jnp.float32) if _trainable(path, p) else p,
+            lambda path, p: jnp.array(
+                p, jnp.float32 if _trainable(path, p) else p.dtype),
             params,
         )
     return state

@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.shardmap_compat import NO_CHECK as _NO_CHECK
-from repro.parallel.shardmap_compat import shard_map
 
 
 def quantize(g: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -80,11 +78,11 @@ def dp_compressed_grads(
                               is_leaf=lambda x: isinstance(x, tuple))
         return grads, new_ef
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(pspec_rep, pspec_batch, pspec_rep),
         out_specs=(pspec_rep, pspec_rep),
-        **_NO_CHECK,
+        check_vma=False,
     )
     return fn(params, batch, ef_state)
 

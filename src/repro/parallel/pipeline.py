@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.shardmap_compat import NO_CHECK as _NO_CHECK
-from repro.parallel.shardmap_compat import shard_map as _shard_map
 
 
 def bubble_fraction(n_stages: int, n_micro: int) -> float:
@@ -94,8 +92,8 @@ def pipeline_apply(
         )
         return outs
 
-    fn = _shard_map(run, mesh=mesh, in_specs=in_specs, out_specs=out_spec,
-                    **_NO_CHECK)
+    fn = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_spec, check_vma=False)
     out = fn(stage_params, xm)
     return out.reshape(b, *x.shape[1:])
 

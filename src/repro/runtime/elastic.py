@@ -125,10 +125,7 @@ def _mesh_tuple(mesh) -> tuple:
 
 
 def _real_devices(devices) -> bool:
-    try:
-        return all(isinstance(d, jax.Device) for d in devices)
-    except TypeError:  # jax without the Device alias
-        return False
+    return all(isinstance(d, jax.Device) for d in devices)
 
 
 class ElasticRunner:

@@ -7,7 +7,12 @@ step, so replay is exact), while a *persistent* failure -- a
 ``DeviceLossError`` from ``runtime.faults``, i.e. a topology change --
 propagates immediately so the elastic runtime (``runtime.elastic
 .ElasticRunner``) can re-mesh and resume instead of retrying a step that
-can never succeed.  ``fail_injector`` lets tests and the chaos harness
+can never succeed.  The step is compiled before the loop, so a program
+the compiler refuses fails once instead of being retried as if it were
+transient.  The step donates its state: a fault after dispatch with no
+checkpoint to restore leaves nothing to replay, so it is raised at once.
+A restored state is placed on the shardings of a fresh one.
+``fail_injector`` lets tests and the chaos harness
 inject failures at chosen steps; steps whose wall time blows past the
 straggler threshold over the step-time EMA are reported as first-class
 degradations on the obs bus rather than silently waited out.
@@ -20,12 +25,15 @@ import time
 from typing import Callable
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro import api
 from repro import obs
 from repro.checkpoint.manager import CheckpointManager
 from repro.data.pipeline import DataConfig, make_batch
 from repro.optim import adamw
+from repro.parallel import rules as rules_lib
+from repro.parallel import specs as specs_lib
 from repro.parallel import steps as steps_lib
 from repro.runtime.faults import DeviceLossError
 
@@ -35,7 +43,8 @@ log = logging.getLogger("repro.trainer")
 @dataclasses.dataclass
 class TrainerConfig:
     n_steps: int = 20
-    ckpt_every: int = 5
+    ckpt_every: int = 5        # 0: no checkpoints (a run whose state is
+                               # not worth the disk, like the chip smoke)
     ckpt_dir: str = "/tmp/repro_ckpt"
     max_retries: int = 3
     log_every: int = 1
@@ -64,7 +73,16 @@ class Trainer:
         # plan_context(mesh=...) around the run.
         self.mesh = mesh
         self.ckpt = CheckpointManager(tcfg.ckpt_dir)
-        self.step_fn = jax.jit(steps_lib.make_train_step(model, opt_cfg, schedule))
+        # The state is donated: the updated state reuses its buffers, so a
+        # model whose optimizer state is half the device's memory fits.
+        self.step_fn = jax.jit(
+            steps_lib.make_train_step(model, opt_cfg, schedule),
+            donate_argnums=(0,))
+        # Set by train(): the executable compiled before the loop (its HLO
+        # shows which kernels the step runs) and the state after the last
+        # step.
+        self.compiled = None
+        self.state = None
         self.metrics: list[dict] = []
         self.kernel_plans: dict[str, object] = {}
 
@@ -93,9 +111,42 @@ class Trainer:
         self.kernel_plans = plans
         return plans
 
+    def state_shardings(self):
+        """Where a fresh state lives on a real multi-device mesh under the
+        ambient sharding rules: each leaf laid out by its logical axes, so
+        no device holds a whole replica it does not need.  None elsewhere
+        (the state stays on the default device)."""
+        mesh = self._plan_mesh()
+        table = rules_lib.current_rules()
+        if (table is None or not isinstance(mesh, jax.sharding.Mesh)
+                or mesh.size <= 1):
+            return None
+        specs = specs_lib.state_specs(
+            self.model.param_defs(), rules_lib.restrict_to_mesh(table, mesh),
+            master=self.opt_cfg.master)
+        return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                            is_leaf=lambda x: isinstance(x, PartitionSpec))
+
+    def _restore_latest(self, like) -> tuple[int, dict] | None:
+        """The latest checkpoint, laid out like a fresh state: a sharded
+        run resumes with its leaves on the shardings it was compiled for."""
+        restored = self.ckpt.restore_latest(like)
+        shardings = self.state_shardings()
+        if restored is None or shardings is None:
+            return restored
+        step, state = restored
+        return step, jax.device_put(state, shardings)
+
     def init_or_restore(self, key) -> tuple[int, dict]:
-        state = steps_lib.init_train_state(self.model, self.opt_cfg, key)
-        restored = self.ckpt.restore_latest(state)
+        shardings = self.state_shardings()
+        if shardings is None:
+            state = steps_lib.init_train_state(self.model, self.opt_cfg, key)
+        else:
+            state = jax.jit(
+                lambda k: steps_lib.init_train_state(self.model,
+                                                     self.opt_cfg, k),
+                out_shardings=shardings)(key)
+        restored = self._restore_latest(state)
         if restored is not None:
             step, state = restored
             log.info("restored checkpoint at step %d", step)
@@ -135,6 +186,14 @@ class Trainer:
                ) -> list[dict]:
         self.plan_hot_kernels()
         step, state = self.init_or_restore(key)
+        if step < self.tcfg.n_steps:
+            # Compile outside the retry loop: a program the compiler
+            # refuses (a kernel Mosaic rejects, a step that does not fit
+            # the device) is refused again on every retry, so it surfaces
+            # here at once.  The loop's first call reuses this executable.
+            self.compiled = self.step_fn.lower(
+                state, make_batch(self.data_cfg, step, self.sharding)
+            ).compile()
         retries = 0
         ema: float | None = None
         n_hist = 0
@@ -167,7 +226,7 @@ class Trainer:
                     log.info("step %d loss %.4f", step, loss)
                 step += 1
                 retries = 0
-                if step % self.tcfg.ckpt_every == 0:
+                if self.tcfg.ckpt_every and step % self.tcfg.ckpt_every == 0:
                     self.ckpt.save(step, state, meta={"loss": loss})
                     if obs.enabled():
                         obs.emit(obs.CheckpointEvent(step=step,
@@ -181,6 +240,12 @@ class Trainer:
                 retries += 1
                 if retries > self.tcfg.max_retries:
                     raise
+                self.ckpt.wait()
+                if (self.ckpt.latest_step() is None and any(
+                        x.is_deleted() for x in jax.tree.leaves(state))):
+                    # The step failed after it took the donated state, and
+                    # no checkpoint holds it: nothing is left to replay.
+                    raise
                 log.warning("step %d failed (%s); restoring (retry %d/%d)",
                             step, e, retries, self.tcfg.max_retries)
                 if obs.enabled():
@@ -189,15 +254,17 @@ class Trainer:
                         detail=f"{type(e).__name__}: {e} "
                                f"(retry {retries}/{self.tcfg.max_retries})"))
                 self._backoff(retries)
-                restored = self.ckpt.restore_latest(state)
+                restored = self._restore_latest(state)
                 if restored is not None:
                     step, state = restored
                     if obs.enabled():
                         obs.emit(obs.CheckpointEvent(step=step,
                                                      action="restore"))
                 # else: replay from current state (failure before 1st ckpt)
-        self.ckpt.save(step, state, meta={"final": True})
-        self.ckpt.wait()
-        if obs.enabled():
-            obs.emit(obs.CheckpointEvent(step=step, action="save"))
+        self.state = state
+        if self.tcfg.ckpt_every:
+            self.ckpt.save(step, state, meta={"final": True})
+            self.ckpt.wait()
+            if obs.enabled():
+                obs.emit(obs.CheckpointEvent(step=step, action="save"))
         return self.metrics

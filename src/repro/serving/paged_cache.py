@@ -39,7 +39,7 @@ __all__ = ["PageManager", "plan_page_geometry", "DEFAULT_PAGE_VMEM"]
 # (the interesting regime), large enough that a page is several sublane
 # tiles.  Like every planner knob it can be overridden via the ambient
 # PlanContext or the ``page_len`` argument.
-DEFAULT_PAGE_VMEM = 1 << 13
+DEFAULT_PAGE_VMEM = 1 << 14
 
 
 def plan_page_geometry(cfg, max_len: int, *, page_len: int | None = None,

@@ -276,3 +276,74 @@ class TestTrainerResumePath:
         step, restored = tr2.init_or_restore(key)
         assert step == 7
         _assert_trees_equal(restored, state)
+
+    def test_sharded_restore_keeps_the_rule_layout(self, tmp_path):
+        """A sharded run that resumes from its checkpoint gets its state
+        back on the shardings the rules give a fresh state, not whole on
+        one device; the resumed (donated) step then runs on it.  Four
+        forced host devices, so in a subprocess (the device count is fixed
+        when jax starts)."""
+        import subprocess
+        import sys
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   XLA_FLAGS="--xla_force_host_platform_device_count=4",
+                   PYTHONPATH=os.pathsep.join([os.path.join(root, "src"),
+                                               root]))
+        proc = subprocess.run(
+            [sys.executable, "-c", _SHARDED_RESUME, str(tmp_path)],
+            env=env, cwd=root, capture_output=True, text=True, timeout=300)
+        assert proc.returncode == 0, proc.stderr[-3000:]
+        got = json.loads(proc.stdout.strip().splitlines()[-1])
+        assert got["step"] == 2
+        assert got["misplaced"] == []
+        assert got["sharded_leaves"] > 0
+        assert got["resumed_steps"] == [2]
+
+
+_SHARDED_RESUME = r"""
+import json, sys
+import jax
+from jax.sharding import NamedSharding
+from repro.data.pipeline import DataConfig
+from repro.launch.mesh import make_test_mesh
+from repro.models import build_model
+from repro.models.config import ModelConfig
+from repro.optim import adamw
+from repro.optim.schedules import make_schedule
+from repro.parallel import rules
+from repro.runtime.trainer import Trainer, TrainerConfig
+
+mesh = make_test_mesh((2, 2))
+table = rules.restrict_to_mesh(rules.make_rules(), mesh)
+cfg = ModelConfig(name="t", family="dense", n_layers=1, d_model=64,
+                  n_heads=2, n_kv_heads=2, d_ff=64, vocab_size=32,
+                  dtype="float32", remat=False)
+
+def trainer(n_steps):
+    return Trainer(
+        build_model(cfg),
+        DataConfig(vocab_size=32, seq_len=16, global_batch=4, d_model=64),
+        adamw.AdamWConfig(), make_schedule("cosine", peak=3e-3, warmup=1,
+                                           total=3),
+        TrainerConfig(n_steps=n_steps, ckpt_every=2, ckpt_dir=sys.argv[1]),
+        mesh=mesh,
+        sharding=NamedSharding(mesh, rules.spec("batch", "seq", rules=table)))
+
+key = jax.random.PRNGKey(0)
+with rules.use_rules(table, mesh=mesh):
+    trainer(2).train(key)
+    resumed = trainer(3)
+    step, state = resumed.init_or_restore(key)
+    want = resumed.state_shardings()
+    leaves = jax.tree_util.tree_leaves_with_path(state)
+    misplaced = [jax.tree_util.keystr(p) for (p, x), sh in
+                 zip(leaves, jax.tree.leaves(want))
+                 if not x.sharding.is_equivalent_to(sh, x.ndim)]
+    sharded = sum(not x.sharding.is_fully_replicated for _, x in leaves)
+    metrics = resumed.train(key)
+print(json.dumps({"step": step, "misplaced": misplaced,
+                  "sharded_leaves": sharded,
+                  "resumed_steps": [m["step"] for m in metrics]}))
+"""

@@ -139,6 +139,61 @@ class TestTrainerClassification:
         with pytest.raises(TransientStepError):
             tr.train(jax.random.PRNGKey(0), fail_injector=inj)
 
+    def test_compile_refusal_is_not_retried(self, tmp_path, monkeypatch):
+        """A step the compiler refuses is refused on every retry: it must
+        surface at once, with no backoff and no restore."""
+        from tests.test_obs import _tiny_trainer
+
+        def refused(state, batch):
+            raise ValueError("refused by the compiler")
+
+        tr = _tiny_trainer(str(tmp_path), n_steps=3, ckpt_every=2)
+        tr.step_fn = jax.jit(refused)
+        sleeps = []
+        monkeypatch.setattr("repro.runtime.trainer.time.sleep",
+                            sleeps.append)
+        ring = obs.RingBufferSink(capacity=100)
+        with obs.session(ring), pytest.raises(ValueError, match="refused"):
+            tr.train(jax.random.PRNGKey(0))
+        assert sleeps == []
+        assert ring.events("degraded") == []
+
+    def test_fault_inside_the_step_is_not_replayed(self, tmp_path,
+                                                   monkeypatch):
+        """A fault raised while the jitted step runs comes after dispatch
+        took the donated state.  With no checkpoint to restore, there is
+        nothing to replay: the original error surfaces after one attempt,
+        not a deleted-array error after every retry."""
+        import numpy as np
+        from jax.experimental import io_callback
+
+        from repro.parallel import steps as steps_lib
+        from tests.test_obs import _tiny_trainer
+
+        tr = _tiny_trainer(str(tmp_path), n_steps=3, ckpt_every=0)
+        inner = steps_lib.make_train_step(
+            tr.model, tr.opt_cfg, lambda s: jax.numpy.float32(1e-3))
+        calls = []
+
+        def device_fault(step):
+            calls.append(int(step))
+            if int(step) == 1:
+                raise RuntimeError("device fault in step 1")
+            return np.int32(0)
+
+        def faulty(state, batch):
+            io_callback(device_fault, jax.ShapeDtypeStruct((), np.int32),
+                        state["opt"]["step"])
+            return inner(state, batch)
+
+        tr.step_fn = jax.jit(faulty, donate_argnums=(0,))
+        monkeypatch.setattr("repro.runtime.trainer.time.sleep",
+                            lambda s: None)
+        with pytest.raises(Exception, match="device fault in step 1"):
+            tr.train(jax.random.PRNGKey(0))
+        assert calls == [0, 1]
+        assert [m["step"] for m in tr.metrics] == [0]
+
     def test_device_loss_propagates_uncaught(self, tmp_path):
         """Persistent failures must escape the retry loop immediately --
         retrying a step on a dead topology cannot succeed."""

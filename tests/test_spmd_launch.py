@@ -52,6 +52,7 @@ from repro.core.planner import clear_plan_cache, plan_cache_keys
 from repro.models import blocks
 from repro.models.config import ModelConfig
 from repro.models.transformer import lm_loss
+from repro.parallel import rules
 
 multidevice = pytest.mark.skipif(
     jax.device_count() < 8,
@@ -222,10 +223,9 @@ class TestGating:
             assert spmd.spmd_mesh() is None
 
     def test_use_fused_kernels_single_device(self):
-        if jax.device_count() == 1:
-            assert blocks.use_fused_kernels()
-        else:
-            assert not blocks.use_fused_kernels()
+        # No mesh anywhere: a one-device program (one chip of a multi-chip
+        # host included) takes the fused kernels.
+        assert blocks.use_fused_kernels()
 
 
 class TestCommModel:
@@ -354,11 +354,15 @@ class TestSpecReport:
 class TestSpmdForward:
     def test_fused_gate_flips_on_mesh(self):
         mesh = env_mesh()
-        assert not blocks.use_fused_kernels()   # 8 devices, no mesh
-        with api.plan_context(mesh=mesh):
+        assert blocks.use_fused_kernels()       # 8 devices, no mesh: one
+        with api.plan_context(mesh=mesh):       # device's program
             assert spmd.spmd_mesh() is mesh
             assert blocks.use_fused_kernels()
-        assert not blocks.use_fused_kernels()
+        with api.plan_context(mesh=mesh, spmd=False):
+            assert not blocks.use_fused_kernels()
+        with rules.use_rules(rules.DEFAULT_RULES, mesh=mesh), \
+                api.plan_context(spmd=False):
+            assert not blocks.use_fused_kernels()
 
     def test_rmsnorm_shard_map_parity_and_local_plan(self):
         mesh = env_mesh()

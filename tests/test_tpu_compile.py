@@ -1,0 +1,122 @@
+"""Compile for a described TPU v5e: what the chip's compiler refuses, found
+without the chip.
+
+Every test here lowers and compiles for a ``v5e:2x2`` topology described in
+the fixture below, with the Pallas interpreter switched off, so Mosaic sees
+the real kernels: block tiling, SMEM/VMEM placement and the scoped-VMEM
+limit.  Nothing runs; a passing compile says nothing about results or
+times.  All such tests live in this one file: only one process may load the
+TPU compiler library, and it keeps it until it exits.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import api
+from repro.kernels import util as kernel_util
+from repro.measure.validate import CASES, args_for
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 -- any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def native(monkeypatch):
+    """Kernels traced in this test compile natively.  Jit caches hold
+    traces made under the other interpret setting, so they are dropped on
+    the way in and on the way out."""
+    monkeypatch.setattr(kernel_util, "INTERPRET", False)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree)
+
+
+def _compile(fn, sharding, *abstract):
+    """Compiled HLO text of ``jit(fn)`` on abstract arguments."""
+    return jax.jit(fn).lower(*_on(sharding, abstract)).compile().as_text()
+
+
+def _compile_kernel(kernel, shape, dtype, sharding):
+    args, scalars = args_for(kernel, shape, dtype)
+    hlo = _compile(lambda *a: api.launch(kernel, *a, **scalars), sharding,
+                   *args)
+    assert "tpu_custom_call" in hlo, f"{kernel} lowered without a kernel"
+
+
+@pytest.mark.parametrize("kernel", sorted(CASES))
+def test_kernel_compiles_at_case(kernel, one_chip, native):
+    shape, dtype = CASES[kernel]
+    _compile_kernel(kernel, shape, dtype, one_chip)
+
+
+# Bandwidth sizes, far beyond VMEM, and the full-vocab loss of a training
+# step at 8 x 128 tokens: the shapes whose blocks the VMEM budget and the
+# token-column layout have to get right.
+LARGE = [
+    ("jacobi", (4096, 4096), "float32"),
+    ("stream.add", (1 << 26,), "float32"),
+    ("xent", (1024, 151936), "bfloat16"),
+]
+
+
+@pytest.mark.parametrize("kernel,shape,dtype", LARGE,
+                         ids=[k for k, _, _ in LARGE])
+def test_kernel_compiles_at_bandwidth_size(kernel, shape, dtype, one_chip,
+                                           native):
+    _compile_kernel(kernel, shape, dtype, one_chip)
+
+
+@pytest.fixture(scope="module")
+def qwen2():
+    from repro.configs import get_config
+    from repro.models import build_model
+
+    model = build_model(get_config("qwen2-0.5b"))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    return model, params
+
+
+def test_qwen2_paged_decode_step_compiles(qwen2, one_chip, native):
+    """The batcher's decode tick at published width: 8 slots, a 1024-token
+    context in 16-token pages."""
+    from repro.models.params import abstract_params
+    from repro.parallel import steps as steps_lib
+
+    model, params = qwen2
+    cache = abstract_params(model.paged_cache_defs(8, 1024, 8 * 64 + 1, 16))
+    tokens = jax.ShapeDtypeStruct((8, 1), jnp.int32)
+    hlo = _compile(steps_lib.make_decode_step(model), one_chip,
+                   params, cache, tokens)
+    assert "tpu_custom_call" in hlo
+
+
+def test_qwen2_loss_gradient_compiles(qwen2, one_chip, native):
+    """The training step's loss gradient at published width, 8 x 128
+    tokens through the fused rmsnorm and xent kernels."""
+    model, params = qwen2
+    batch = {"tokens": jax.ShapeDtypeStruct((8, 128), jnp.int32),
+             "labels": jax.ShapeDtypeStruct((8, 128), jnp.int32)}
+    hlo = _compile(jax.grad(model.loss), one_chip, params, batch)
+    assert "tpu_custom_call" in hlo
