@@ -98,6 +98,8 @@ def _coefficients(dtype, shape: tuple[int, ...]) -> jax.Array:
 
 
 _SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+# The collision's name in a profile, in either layout and on any mesh.
+KERNEL_NAME = "lbm_collide"
 
 
 def _whole(shape: tuple[int, ...]) -> pl.BlockSpec:
@@ -113,6 +115,7 @@ def collide_soa(f: jax.Array, omega: float, *, bs: int = 2048) -> jax.Array:
     coef = (4, Q, 1, LANES)
     out = pl.pallas_call(
         _soa_kernel,
+        name=KERNEL_NAME,
         grid=(s // bs,),
         in_specs=[_SMEM, _whole(coef), spec],
         out_specs=spec,
@@ -132,6 +135,7 @@ def collide_ivjk(f: jax.Array, omega: float, *, bsb: int = 16) -> jax.Array:
     coef = (4, 1, Q, LANES)
     return pl.pallas_call(
         _ivjk_kernel,
+        name=KERNEL_NAME,
         grid=(sb // bsb,),
         in_specs=[_SMEM, _whole(coef), spec],
         out_specs=spec,

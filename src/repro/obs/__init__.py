@@ -15,7 +15,9 @@ seams of the launch path, the trainer, the batcher, and the validator,
 delivered to pluggable sinks (``obs.sinks``) through an ambient nestable
 session (``obs.bus``) that mirrors ``api.plan_context``.  The default
 sink is a ``NullSink`` and producers gate on ``obs.enabled()``, so an
-uninstrumented process pays nothing.  See docs/OBS.md.
+uninstrumented process pays nothing.  Host spans (``obs.span``) go to
+the JAX profiler's trace instead, on the device trace's clock
+(``obs.spans``).  See docs/OBS.md.
 """
 from repro.obs.bus import (
     current_sinks,
@@ -51,6 +53,7 @@ from repro.obs.sinks import (
     RingBufferSink,
     Sink,
 )
+from repro.obs.spans import SPAN_NAMES, span
 
 __all__ = [
     "session", "emit", "enabled", "current_sinks",
@@ -61,5 +64,5 @@ __all__ = [
     "AdmissionEvent", "BatcherTickEvent", "PagePoolEvent",
     "PreemptionEvent", "RequestAbandonedEvent", "ProfileDriftEvent",
     "MeshChangeEvent", "ResumeEvent", "DegradedEvent",
-    "EVENT_KINDS",
+    "EVENT_KINDS", "SPAN_NAMES", "span",
 ]

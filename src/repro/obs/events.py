@@ -185,13 +185,19 @@ class CheckpointEvent(Event):
 
 @dataclasses.dataclass(frozen=True)
 class AdmissionEvent(Event):
-    """The continuous batcher admitted a request into a decode slot."""
+    """The continuous batcher admitted a request into a decode slot.
+
+    ``waited_s`` is the request's time in the queue on
+    ``time.perf_counter``: from ``submit``, or from its requeue after a
+    preemption.
+    """
 
     kind: ClassVar[str] = "admission"
 
     rid: int
     slot: int
     queue_depth: int
+    waited_s: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -202,6 +208,11 @@ class BatcherTickEvent(Event):
     (physical minus requested slots); ``free_slots`` is requested slots
     with no tenant.  Together they are the tick's packing waste: rows the
     decode batch computes that serve no request.
+
+    ``eager_updates`` counts the device updates the batcher sent outside
+    the step program since the previous tick: page-table writes (one per
+    page claimed, one per slot's pages released) and slot resets (one
+    per cache leaf with a batch axis).  Each is a dispatch of its own.
     """
 
     kind: ClassVar[str] = "batcher_tick"
@@ -214,6 +225,7 @@ class BatcherTickEvent(Event):
     free_slots: int
     pad_slots: int
     queue_depth: int
+    eager_updates: int = 0
 
 
 @dataclasses.dataclass(frozen=True)

@@ -15,9 +15,11 @@ reports, per section:
     bytes;
   * trainer -- steps, loss trajectory, mean step wall time, checkpoints;
   * batcher -- admissions, peak queue depth, mean packing waste (free +
-    tile-pad slots as a fraction of the physical decode batch), plus the
-    paged-KV signals: mean/peak page-pool utilization, preemptions (by
-    reason), and requests abandoned at a run's tick budget;
+    tile-pad slots as a fraction of the physical decode batch), queue
+    wait (p50/p95/max of ``admission.waited_s``) and device updates sent
+    outside the step program per tick, plus the paged-KV signals:
+    mean/peak page-pool utilization, preemptions (by reason), and
+    requests abandoned at a run's tick budget;
   * elastic -- mesh changes (with the surviving topology), elastic
     resumes (restore step, re-chunked batch), and degraded-mode events
     by reason (stragglers, transient retries, retired surplus devices,
@@ -33,6 +35,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import math
 import sys
 
 __all__ = ["aggregate", "render", "main"]
@@ -61,6 +64,14 @@ def _read_records(paths) -> tuple[list[dict], int]:
     return records, bad
 
 
+def _percentile(values: list[float], q: float):
+    """Nearest-rank percentile (None for no values)."""
+    if not values:
+        return None
+    ordered = sorted(values)
+    return ordered[max(0, math.ceil(q / 100 * len(ordered)) - 1)]
+
+
 def _mesh_str(mesh) -> str:
     if not mesh:
         return "-"
@@ -78,7 +89,8 @@ def aggregate(records: list[dict]) -> dict:
              "sum_step_s": 0.0, "checkpoint_saves": 0,
              "checkpoint_restores": 0}
     batcher = {"admissions": 0, "max_queue_depth": 0, "ticks": 0,
-               "sum_waste_frac": 0.0, "page_ticks": 0,
+               "sum_waste_frac": 0.0, "sum_eager_updates": 0,
+               "waits_s": [], "page_ticks": 0,
                "sum_page_util": 0.0, "peak_page_util": None,
                "preemptions": 0, "preempt_reasons": {},
                "abandoned": 0}
@@ -151,12 +163,15 @@ def aggregate(records: list[dict]) -> dict:
             batcher["admissions"] += 1
             batcher["max_queue_depth"] = max(
                 batcher["max_queue_depth"], int(rec.get("queue_depth", 0)))
+            if rec.get("waited_s") is not None:
+                batcher["waits_s"].append(float(rec["waited_s"]))
         elif kind == "batcher_tick":
             batcher["ticks"] += 1
             padded = int(rec.get("padded_slots", 0)) or 1
             waste = int(rec.get("free_slots", 0)) + int(
                 rec.get("pad_slots", 0))
             batcher["sum_waste_frac"] += waste / padded
+            batcher["sum_eager_updates"] += int(rec.get("eager_updates", 0))
             batcher["max_queue_depth"] = max(
                 batcher["max_queue_depth"], int(rec.get("queue_depth", 0)))
         elif kind == "page_pool":
@@ -199,6 +214,13 @@ def aggregate(records: list[dict]) -> dict:
         train["sum_step_s"] / train["steps"] if train["steps"] else None)
     batcher["mean_waste_frac"] = (
         batcher["sum_waste_frac"] / batcher["ticks"]
+        if batcher["ticks"] else None)
+    waits = batcher.pop("waits_s")
+    batcher["queue_wait_p50_s"] = _percentile(waits, 50)
+    batcher["queue_wait_p95_s"] = _percentile(waits, 95)
+    batcher["queue_wait_max_s"] = max(waits) if waits else None
+    batcher["mean_eager_updates"] = (
+        batcher["sum_eager_updates"] / batcher["ticks"]
         if batcher["ticks"] else None)
     batcher["mean_page_util"] = (
         batcher["sum_page_util"] / batcher["page_ticks"]
@@ -269,6 +291,11 @@ def render(summary: dict) -> str:
         f"batcher: {ba['admissions']} admission(s), {ba['ticks']} tick(s), "
         f"peak queue {ba['max_queue_depth']}, mean packing waste "
         + (f"{waste:.1%}" if waste is not None else "-"))
+    lines.append(
+        f"  queue wait p50 {_fmt(ba['queue_wait_p50_s'])}s, "
+        f"p95 {_fmt(ba['queue_wait_p95_s'])}s, "
+        f"max {_fmt(ba['queue_wait_max_s'])}s; eager device updates "
+        f"{_fmt(ba['mean_eager_updates'])}/tick")
     util = ba["mean_page_util"]
     reasons = "; ".join(f"{r}: {n}" for r, n in
                         sorted(ba["preempt_reasons"].items()))

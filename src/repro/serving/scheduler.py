@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import time
 from collections import deque
 from typing import Iterable
 
@@ -62,6 +63,7 @@ class Request:
     fed: int = 0                      # replay tokens fed so far
     restart_target: int = 0           # replay horizon after a preemption
     preemptions: int = 0
+    queued_at: float | None = None    # perf_counter when (re)queued
 
     @property
     def replay_len(self) -> int:
@@ -181,6 +183,12 @@ class ContinuousBatcher:
         self.queue: deque[Request] = deque()
         self.ticks = 0
         self.completed: dict[int, list[int]] = {}
+        # Device updates sent outside the step program since the last
+        # tick (page-table writes and slot resets); each is a dispatch
+        # of its own, reported on BatcherTickEvent.eager_updates.
+        self._eager_updates = 0
+        self._reset_leaves = sum(
+            ax >= 0 for ax in jax.tree.leaves(self._batch_axes))
 
     # ---- layout planning ---------------------------------------------------
     def _batch_plan(self, rows: int):
@@ -224,6 +232,7 @@ class ContinuousBatcher:
                 raise ValueError(
                     f"request {req.rid}: empty prompt (serving needs at "
                     f"least one prompt token)")
+            req.queued_at = time.perf_counter()
             self.queue.append(req)
         self._admit()
 
@@ -249,6 +258,7 @@ class ContinuousBatcher:
         freed = self.pages.release(slot)
         if freed:
             self.cache["pages"] = self.cache["pages"].at[slot].set(0)
+            self._eager_updates += 1
         return freed
 
     def _preempt(self, victim: int, reason: str) -> int:
@@ -261,6 +271,7 @@ class ContinuousBatcher:
         freed = self._release_slot_pages(victim)
         self.slot_req[victim] = None
         self._slot_pos[victim] = 0
+        req.queued_at = time.perf_counter()
         self.queue.appendleft(req)
         if obs.enabled():
             obs.emit(obs.PreemptionEvent(
@@ -306,6 +317,7 @@ class ContinuousBatcher:
                     for lp, phys in got:
                         pages_leaf = pages_leaf.at[slot, lp].set(phys)
                     self.cache["pages"] = pages_leaf
+                    self._eager_updates += len(got)
                 return True
             if not self._preempt_one(exclude=slot, allow_decode=decoding,
                                      reason=reason):
@@ -371,35 +383,74 @@ class ContinuousBatcher:
         return preempted
 
     def _admit(self) -> None:
-        admitted = False
-        for s in range(self.slots):
-            if self.slot_req[s] is None and self.queue:
-                if not self._can_admit(self.queue[0]):
-                    break        # FIFO: no head-of-line bypass
-                req = self.queue.popleft()
-                self.slot_req[s] = req
-                self._slot_pos[s] = 0
-                self._seq += 1
-                self._slot_seq[s] = self._seq
-                self.cache = self._reset_slot(self.cache, s)
-                admitted = True
-                if obs.enabled():
-                    obs.emit(obs.AdmissionEvent(
-                        rid=req.rid, slot=s, queue_depth=len(self.queue)))
-        if admitted:
-            self._note_admitted_plans()
+        with obs.span("batcher.admit") as span:
+            admitted = []
+            for s in range(self.slots):
+                if self.slot_req[s] is None and self.queue:
+                    if not self._can_admit(self.queue[0]):
+                        break        # FIFO: no head-of-line bypass
+                    req = self.queue.popleft()
+                    self.slot_req[s] = req
+                    self._slot_pos[s] = 0
+                    self._seq += 1
+                    self._slot_seq[s] = self._seq
+                    self.cache = self._reset_slot(self.cache, s)
+                    self._eager_updates += self._reset_leaves
+                    admitted.append(req.rid)
+                    if obs.enabled():
+                        obs.emit(obs.AdmissionEvent(
+                            rid=req.rid, slot=s,
+                            queue_depth=len(self.queue),
+                            waited_s=time.perf_counter() - req.queued_at))
+            if admitted:
+                self._note_admitted_plans()
+                if span.is_enabled():
+                    span.set_metadata(rids=" ".join(map(str, admitted)))
 
     # ------------------------------------------------------------------
     def step(self) -> None:
-        self._note_admitted_plans()
+        """One tick.  Each phase is a host span (``obs.SPAN_NAMES``) in the
+        profiler's trace, so the device's idle time between two decode
+        programs can be split by what the host was doing."""
+        with obs.span("batcher.tick", tick=self.ticks + 1):
+            with obs.span("batcher.plan"):
+                self._note_admitted_plans()
+            with obs.span("batcher.pages"):
+                width, advance = self._claim_pages()
+            with obs.span("batcher.feed"):
+                feed, nvalid = self._build_feed(width, advance)
+                # The chunk step is only needed when rows advance unevenly
+                # (chunked prefill, or a stalled slot under page pressure);
+                # the uniform case keeps the legacy single-token decode
+                # program.
+                active = [n for n in advance if n]
+                uniform = width == 1 and len(active) == sum(
+                    r is not None for r in self.slot_req)
+                if uniform:
+                    program, args = self.decode, (jnp.asarray(feed),)
+                else:
+                    program = self._chunk
+                    args = (jnp.asarray(feed), jnp.asarray(nvalid))
+            with obs.span("batcher.dispatch"):
+                nxt, self.cache = program(self.params, self.cache, *args)
+            with obs.span("batcher.sync"):
+                nxt = np.asarray(nxt)[:, 0]
+            with obs.span("batcher.retire"):
+                self.ticks += 1
+                self._emit_tick_events()
+                self._retire(advance, nxt)
+            self._admit()
+
+    def _claim_pages(self) -> tuple[int, list[int]]:
+        """The tick's feed width and each slot's advance.  Paged slots must
+        hold pages for every position they will write *before* the device
+        call.  Decoders claim first (decode priority), then prefillers
+        oldest-first; a prefiller that cannot get pages stalls (advance
+        0) this tick."""
         width = 1
         if self.prefill_chunk > 1 and any(
                 r is not None and r.prefilling for r in self.slot_req):
             width = self.prefill_chunk
-        # Per-slot advance this tick; paged slots must hold pages for every
-        # position they will write *before* the device call.  Decoders
-        # claim first (decode priority), then prefillers oldest-first; a
-        # prefiller that cannot get pages stalls (advance 0) this tick.
         advance = [0] * self.slots
         order = sorted(
             (s for s, r in enumerate(self.slot_req) if r is not None),
@@ -416,6 +467,11 @@ class ContinuousBatcher:
                                           decoding=not req.prefilling):
                     continue
             advance[s] = n
+        return width, advance
+
+    def _build_feed(self, width: int, advance: list[int]):
+        """The tick's tokens ``(padded_slots, width)`` and per-row valid
+        counts, on the host."""
         feed = np.zeros((self.padded_slots, width), np.int32)
         nvalid = np.zeros((self.padded_slots,), np.int32)
         for s, req in enumerate(self.slot_req):
@@ -427,42 +483,38 @@ class ContinuousBatcher:
                     feed[s, j] = req.replay_token(req.fed + j)
             else:
                 feed[s, 0] = req.generated[-1]
-        # The chunk step is only needed when rows advance unevenly (chunked
-        # prefill, or a stalled slot under page pressure); the uniform case
-        # keeps the legacy single-token decode program.
-        active = [n for n in advance if n]
-        uniform = width == 1 and len(active) == sum(
-            r is not None for r in self.slot_req)
-        if uniform:
-            nxt, self.cache = self.decode(self.params, self.cache,
-                                          jnp.asarray(feed))
-        else:
-            nxt, self.cache = self._chunk(self.params, self.cache,
-                                          jnp.asarray(feed),
-                                          jnp.asarray(nvalid))
-        nxt = np.asarray(nxt)[:, 0]
-        self.ticks += 1
-        if obs.enabled():
-            # Packing waste is the tick's dead rows: slots with no tenant
-            # (free) plus the tile padding the planner chose (pad).  Both
-            # rows run through the decode step anyway -- the signal the
-            # report aggregates into a mean waste fraction.
-            n_prefill = sum(r is not None and r.prefilling
-                            for r in self.slot_req)
-            n_decode = sum(r is not None and not r.prefilling
-                           for r in self.slot_req)
-            obs.emit(obs.BatcherTickEvent(
-                tick=self.ticks, n_prefill=n_prefill, n_decode=n_decode,
-                slots=self.slots, padded_slots=self.padded_slots,
-                free_slots=self.slots - n_prefill - n_decode,
-                pad_slots=self.padded_slots - self.slots,
-                queue_depth=len(self.queue)))
-            if self.pages is not None:
-                obs.emit(obs.PagePoolEvent(
-                    tick=self.ticks, used_pages=self.pages.used_pages,
-                    free_pages=self.pages.free_pages,
-                    live_pages=self.pages.live_pages,
-                    page_len=self.geometry.page_len))
+        return feed, nvalid
+
+    def _emit_tick_events(self) -> None:
+        """The tick's occupancy and pool events; starts the next count of
+        eager updates whether or not anyone listens."""
+        eager, self._eager_updates = self._eager_updates, 0
+        if not obs.enabled():
+            return
+        # Packing waste is the tick's dead rows: slots with no tenant
+        # (free) plus the tile padding the planner chose (pad).  Both
+        # rows run through the decode step anyway -- the signal the
+        # report aggregates into a mean waste fraction.
+        n_prefill = sum(r is not None and r.prefilling
+                        for r in self.slot_req)
+        n_decode = sum(r is not None and not r.prefilling
+                       for r in self.slot_req)
+        obs.emit(obs.BatcherTickEvent(
+            tick=self.ticks, n_prefill=n_prefill, n_decode=n_decode,
+            slots=self.slots, padded_slots=self.padded_slots,
+            free_slots=self.slots - n_prefill - n_decode,
+            pad_slots=self.padded_slots - self.slots,
+            queue_depth=len(self.queue), eager_updates=eager))
+        if self.pages is not None:
+            obs.emit(obs.PagePoolEvent(
+                tick=self.ticks, used_pages=self.pages.used_pages,
+                free_pages=self.pages.free_pages,
+                live_pages=self.pages.live_pages,
+                page_len=self.geometry.page_len))
+
+    def _retire(self, advance: list[int], nxt: np.ndarray) -> None:
+        """Advance each slot by the tokens it fed, record new tokens, and
+        free the slots (and pages) of finished requests."""
         for s, req in enumerate(self.slot_req):
             if req is None or not advance[s]:
                 continue
@@ -479,7 +531,6 @@ class ContinuousBatcher:
                 self._slot_pos[s] = 0
                 if self.pages is not None:
                     self._release_slot_pages(s)
-        self._admit()
 
     @property
     def busy(self) -> bool:

@@ -442,6 +442,89 @@ class TestBatcherEvents:
         b = ContinuousBatcher(_EchoModel(), {}, slots=1, max_len=8)
         b.run([Request(rid=0, prompt=[2], max_new_tokens=1)])
         assert calls == []
+        # A tick with no session, spans and counters included, makes no
+        # sink call either.
+        b.submit([Request(rid=1, prompt=[2, 3], max_new_tokens=2)])
+        b.step()
+        assert b.ticks == 2 and calls == []
+
+    def test_admission_waited_s_behind_a_full_batch(self):
+        from repro.serving.scheduler import ContinuousBatcher, Request
+
+        b = ContinuousBatcher(_EchoModel(), {}, slots=1, max_len=8)
+        ring = obs.RingBufferSink()
+        with obs.session(ring):
+            b.run([Request(rid=i, prompt=[3, 4], max_new_tokens=2)
+                   for i in range(2)])
+        first, second = ring.events("admission")
+        assert (first.rid, second.rid) == (0, 1)
+        # rid 1 waited in the queue for rid 0's three ticks.
+        assert 0 <= first.waited_s < second.waited_s
+        # A dense cache with no batch-axis leaves sends no eager update.
+        assert {t.eager_updates for t in ring.events("batcher_tick")} == {0}
+
+    def test_tick_spans_nest_in_code_order(self, tmp_path):
+        """Each tick is one ``batcher.tick`` span holding one span per
+        phase, in the order the code runs them: no phase is opened per
+        slot, and ``batcher.admit`` also stands alone for ``submit``."""
+        import jax
+        from jax.profiler import ProfileData
+
+        from repro.serving.scheduler import ContinuousBatcher, Request
+
+        b = ContinuousBatcher(_EchoModel(), {}, slots=2, max_len=8)
+        b.run([Request(rid=-1, prompt=[1], max_new_tokens=1)])   # compile
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            b.run([Request(rid=i, prompt=[3, 4], max_new_tokens=2)
+                   for i in range(3)])
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.glob("**/*.xplane.pb")
+        spans = sorted(
+            ((ev.start_ns, -ev.end_ns, ev.name, {k: v for k, v in ev.stats})
+             for plane in ProfileData.from_file(str(path)).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("batcher.")))
+        assert {name for _, _, name, _ in spans} == set(obs.SPAN_NAMES)
+        ticks = [(s, -e, stats) for s, e, name, stats in spans
+                 if name == "batcher.tick"]
+        assert [stats["tick"] for _, _, stats in ticks] == list(
+            range(2, b.ticks + 1))
+        order = ["batcher.plan", "batcher.pages", "batcher.feed",
+                 "batcher.dispatch", "batcher.sync", "batcher.retire",
+                 "batcher.admit"]
+        inside = set()
+        for start, end, _ in ticks:
+            kids = [(s, name) for s, e, name, _ in spans
+                    if start <= s and -e <= end and name != "batcher.tick"]
+            assert [name for _, name in kids] == order
+            inside.update(kids)
+        # submit() admitted rids 0 and 1 outside any tick; rid 2 is named
+        # by the admit span of the tick that freed its slot.
+        alone = [stats for s, _, name, stats in spans
+                 if name == "batcher.admit" and (s, name) not in inside]
+        assert alone[0] == {"rids": "0 1"}
+        assert {"rids": 2} in [stats for _, _, name, stats in spans
+                               if name == "batcher.admit"]
+
+    def test_every_span_opened_in_src_is_listed(self):
+        """Spans are opened through ``obs.span`` with a literal name from
+        ``obs.SPAN_NAMES``; nothing else in ``src/`` writes to the
+        profiler's trace."""
+        import re
+
+        import repro
+
+        src = Path(repro.__path__[0])
+        opened = set()
+        for f in src.rglob("*.py"):
+            text = f.read_text()
+            if f.name != "spans.py":
+                assert "TraceAnnotation" not in text, f
+            opened |= set(re.findall(r'\bspan\(\s*"([^"]+)"', text))
+        assert opened == set(obs.SPAN_NAMES)
 
 
 # ---------------------------------------------------------------------------
@@ -535,10 +618,10 @@ def _sample_events() -> list:
         events.TrainStepEvent(step=1, loss=3.1, grad_norm=0.9, step_s=0.3),
         events.CheckpointEvent(step=2, action="save"),
         events.CheckpointEvent(step=2, action="restore"),
-        events.AdmissionEvent(rid=0, slot=0, queue_depth=4),
+        events.AdmissionEvent(rid=0, slot=0, queue_depth=4, waited_s=0.25),
         events.BatcherTickEvent(tick=1, n_prefill=1, n_decode=1, slots=4,
                                 padded_slots=8, free_slots=2, pad_slots=4,
-                                queue_depth=1),
+                                queue_depth=1, eager_updates=5),
         events.ProfileDriftEvent(path="p.json", cell="rmsnorm (8, 128)",
                                  detail="block_shape moved"),
     ]
@@ -634,6 +717,29 @@ class TestReport:
         text = report.render(s)
         assert "elastic: 1 mesh change(s)" in text
         assert "data=3,model=2" in text
+
+    def test_batcher_queue_wait_and_eager_updates(self):
+        evs = [events.AdmissionEvent(rid=i, slot=0, queue_depth=0,
+                                     waited_s=w)
+               for i, w in enumerate([0.4, 0.1, 0.3, 0.2, 2.0])]
+        evs += [events.BatcherTickEvent(
+            tick=t, n_prefill=0, n_decode=1, slots=1, padded_slots=1,
+            free_slots=0, pad_slots=0, queue_depth=0, eager_updates=n)
+            for t, n in enumerate([4, 0, 0, 1], 1)]
+        # A stream written before the fields existed still aggregates.
+        evs_old = [{"kind": "admission", "ts": 0.0, "rid": 9, "slot": 0,
+                    "queue_depth": 0}]
+        s = report.aggregate([e.to_record() for e in evs] + evs_old)
+        ba = s["batcher"]
+        assert ba["queue_wait_p50_s"] == pytest.approx(0.3)
+        assert ba["queue_wait_p95_s"] == pytest.approx(2.0)
+        assert ba["queue_wait_max_s"] == pytest.approx(2.0)
+        assert ba["mean_eager_updates"] == pytest.approx(1.25)
+        text = report.render(s)
+        assert "queue wait p50 0.3s, p95 2s, max 2s" in text
+        assert "eager device updates 1.25/tick" in text
+        empty = report.aggregate([])["batcher"]
+        assert empty["queue_wait_p50_s"] is empty["mean_eager_updates"] is None
 
     def test_render_is_stable_when_empty(self):
         text = report.render(report.aggregate([]))

@@ -185,6 +185,35 @@ def test_preemption_decode_priority_and_replay():
     assert got == want                          # replay is invisible
 
 
+def test_eager_updates_count_page_writes_and_slot_resets():
+    """``BatcherTickEvent.eager_updates`` counts each device update sent
+    outside the step program since the previous tick: a slot reset writes
+    the paged cache's three batch-axis leaves (idx, act, pages), a page
+    claim one page-table entry, a retirement the slot's page-table row.
+    A request behind a full batch waits in the queue (``waited_s``)."""
+    cfg = reduce_for_smoke(get_config("qwen2-0.5b"))
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    b = ContinuousBatcher(model, params, slots=1, max_len=32,
+                          kv_cache="paged", page_len=8)
+    assert b.geometry.page_len == 8
+    # rid 0 feeds 19 prompt tokens and its first output: positions 0..19,
+    # crossing two page boundaries.  rid 1 waits for its slot.
+    reqs = [Request(rid=0, prompt=list(range(1, 20)), max_new_tokens=2),
+            Request(rid=1, prompt=[5, 6, 7], max_new_tokens=1)]
+    ring = obs.RingBufferSink(capacity=10_000)
+    with obs.session(ring):
+        b.run(reqs)
+    counts = [t.eager_updates for t in ring.events("batcher_tick")]
+    # tick 1: reset + page 0; ticks 9 and 17: pages 1 and 2; tick 21:
+    # rid 0's release, rid 1's reset and its page 0.
+    want = [3 + 1] + [0] * 7 + [1] + [0] * 7 + [1] + [0] * 3 + [1 + 3 + 1]
+    assert counts == want + [0, 0]
+    first, second = ring.events("admission")
+    assert (first.rid, second.rid) == (0, 1)
+    assert 0 <= first.waited_s < second.waited_s
+
+
 def test_pool_shrink_degrades_gracefully():
     """Chaos satellite: losing page capacity mid-stream (a host behind the
     pool goes away) shrinks the live pool via the preemption-by-replay

@@ -10,6 +10,8 @@ TPU compiler library, and it keeps it until it exits.
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -86,6 +88,22 @@ LARGE = [
 def test_kernel_compiles_at_bandwidth_size(kernel, shape, dtype, one_chip,
                                            native):
     _compile_kernel(kernel, shape, dtype, one_chip)
+
+
+@pytest.mark.parametrize("layout", ["ivjk", "soa"])
+def test_lbm_collision_keeps_its_name(layout, one_chip, native):
+    """The collision's HLO instruction, and so its name in a profile, is
+    ``lbm_collide.N`` whatever function or loop encloses it."""
+    from repro.kernels.lbm import kernel, ops
+
+    f = jax.ShapeDtypeStruct((19, 16, 16, 128), jnp.float32)
+    hlo = _compile(lambda f: ops.lbm_run(f, 1.2, 2, layout=layout),
+                   one_chip, f)
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    for line in calls:
+        assert re.match(rf"\s*(ROOT )?%{kernel.KERNEL_NAME}\.\d+ = ", line)
 
 
 @pytest.fixture(scope="module")
