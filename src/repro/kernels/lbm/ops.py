@@ -1,11 +1,26 @@
-"""LBM D3Q19 step: registry entries per layout, traffic accounting.
+"""LBM D3Q19 step: registry entries per layout, path choice, traffic
+accounting.
 
 ``lbm.soa`` and ``lbm.ivjk`` register as separate kernels (the paper's Fig. 7
 layout comparison is a *planning* decision, so it lives in the kernel name).
-Pad multiples and block shapes come from the planner's VMEM-budget analysis
-of the 19+19 streams; the flatten/pad helper routes through the plan's
-padded shape, so the lattice is padded exactly once even when the plan has
-widened the minor dim beyond the block multiple (e.g. for a mesh).
+
+Two paths run a sweep on one device:
+
+  * fused (``lbm.ivjk`` only): one ``kernel.pull_collide_ivjk`` per sweep,
+    propagation and collision together, the lattice kept in IvJK planes
+    between the sweeps of ``lbm_run``.  Taken when the lattice is on a
+    single device (no SPMD mesh), has no mask, is 32-bit, Z is a multiple
+    of 128, Y of 8, and its X planes fit VMEM (``_unfused_reason``).
+    ``lbm_run`` and every ``lbm.ivjk`` launch report the path they take as
+    an ``obs`` ``LbmPathEvent``.
+  * unfused (``lbm.soa``, and ``lbm.ivjk`` otherwise): propagation by
+    ``jnp.roll`` (``ref.propagate``), then the planned Pallas collision.
+    Pad multiples and block shapes come from the planner's VMEM-budget
+    analysis of the 19+19 streams; the flatten/pad helper routes through
+    the plan's padded shape, so the lattice is padded exactly once even
+    when the plan has widened the minor dim beyond the block multiple (e.g.
+    for a mesh), and ``_step_ivjk`` transposes into and out of the IvJK
+    layout around the collision on every sweep.
 
 Under an SPMD mesh the lattice shards its X axis over the data axis with
 *per-direction* halo depths: of D3Q19's 19 directions, 5 have c_x = +1,
@@ -14,7 +29,8 @@ ppermutes two (5, 1, Y, Z) slabs around the (periodic) ring instead of
 replicating the whole lattice.  The shard body is overlapped
 (docs/OVERLAP.md): slabs are issued first, the interior planes (which pull
 only from locally-resident planes) propagate+collide while they fly, and
-only the two boundary planes read the arriving slabs.
+only the two boundary planes read the arriving slabs.  It runs the
+unfused path.
 """
 from __future__ import annotations
 
@@ -29,10 +45,12 @@ from repro.api.registry import register_kernel
 from repro.api.spmd import Partitioning
 from repro.core.aliasing import InterleavedMemoryModel
 from repro.core.autotune import StreamSignature, choose_layout
-from repro.core.layout import LANES, round_up
+from repro.core.layout import LANES, SUBLANES, VMEM_LIMIT_BYTES, round_up
 from repro.kernels._shims import deprecated_wrapper
 from repro.kernels.lbm import kernel, ref
 from repro.kernels.lbm.ref import Q
+from repro.obs import bus as obs_bus
+from repro.obs import events as obs_events
 
 LAYOUTS = ("soa", "ivjk")
 
@@ -87,6 +105,63 @@ def _step_ivjk(f, omega, mask, *, plan):
     post = kernel.collide_ivjk(ivjk, omega, bsb=plan.block_rows)
     post = post.transpose(1, 0, 2).reshape(Q, -1)[:, :s].reshape(f.shape)
     return post if mask is None else jnp.where(mask[None], post, f)
+
+
+# ---- fused sweep: the lattice kept in IvJK planes ---------------------------
+
+# The fused sweep's planes may take three quarters of the scoped VMEM
+# limit; the rest is Mosaic's own scratch.
+_FUSED_VMEM = VMEM_LIMIT_BYTES * 3 // 4
+
+
+def _unfused_reason(shape, dtype, *, masked=False, spmd=False) -> str:
+    """Why a sweep of ``lbm.ivjk`` at ``shape`` cannot be the fused
+    pull+collide kernel ("" when it can).  The fused kernel pulls across
+    whole 128-lane z chunks and 8-row y strips of a single device's
+    lattice, with three X planes and the one in flight in VMEM."""
+    _, _, y, z = shape
+    if spmd:
+        return "spmd mesh"
+    if masked:
+        return "mask"
+    if jnp.dtype(dtype).itemsize != 4:
+        return f"dtype {jnp.dtype(dtype).name}"
+    if z % LANES:
+        return f"Z {z} not a multiple of {LANES}"
+    if y % SUBLANES:
+        return f"Y {y} not a multiple of {SUBLANES}"
+    need = kernel.pull_collide_vmem_bytes(y, z // LANES, 4)
+    if need > _FUSED_VMEM:
+        return f"planes need {need} B of VMEM > {_FUSED_VMEM}"
+    return ""
+
+
+def _fused_path(shape, dtype, **why) -> bool:
+    """Choose the path of an ``lbm.ivjk`` launch or run, and report it."""
+    reason = _unfused_reason(shape, dtype, **why)
+    if obs_bus.enabled():
+        obs_bus.emit(obs_events.LbmPathEvent(
+            kernel="lbm.ivjk", shape=tuple(int(n) for n in shape),
+            dtype=jnp.dtype(dtype).name,
+            path="unfused" if reason else "fused", reason=reason))
+    return not reason
+
+
+@functools.partial(jax.jit, static_argnames=("iters",))
+def _run_fused(f, omega, *, iters):
+    """``iters`` fused sweeps of the (Q, X, Y, Z) lattice: the first reads
+    it, the last writes it, and the lattice stays in IvJK planes, updated
+    in place, in between."""
+    _, _, ny, nz = f.shape
+    sweep = functools.partial(kernel.pull_collide_ivjk, omega=omega, ny=ny,
+                              nz=nz)
+    if iters < 1:
+        return f
+    if iters == 1:
+        return sweep(f, soa_in=True, soa_out=True)
+    planes = jax.lax.fori_loop(0, iters - 2, lambda _, g: sweep(g),
+                               sweep(f, soa_in=True))
+    return sweep(planes, soa_out=True)
 
 
 def _lbm_ref(f, *, omega, mask=None):
@@ -274,7 +349,11 @@ def _launch_soa(plan, f, *, omega, mask=None):
                  spmd_body=_spmd_lbm_ivjk)
 def _launch_ivjk(plan, f, *, omega, mask=None):
     """Collision with directions interleaved at lane granularity
-    (the paper's auto-skewed IvJK layout)."""
+    (the paper's auto-skewed IvJK layout): the fused pull+collide kernel
+    where the shape allows it, else propagation by roll and the planned
+    collision."""
+    if _fused_path(f.shape, f.dtype, masked=mask is not None):
+        return _run_fused(f, omega, iters=1)
     return _step_ivjk(f, omega, mask, plan=plan)
 
 
@@ -294,11 +373,9 @@ def lbm_step(
 
 @functools.partial(jax.jit, static_argnames=("iters", "layout", "plan"))
 def _run(f, omega, *, iters, layout, plan):
+    step = _step_soa if layout == "soa" else _step_ivjk
     return jax.lax.fori_loop(
-        0, iters,
-        lambda _, x: dispatch.launch(f"lbm.{layout}", x, omega=omega,
-                                     plan=plan), f,
-    )
+        0, iters, lambda _, x: step(x, omega, None, plan=plan), f)
 
 
 def lbm_run(f: jax.Array, omega: float, iters: int, *,
@@ -310,6 +387,8 @@ def lbm_run(f: jax.Array, omega: float, iters: int, *,
     # consecutive steps pipeline -- step k+1's halo slabs fly while step
     # k's interior planes are still colliding.
     if spmd_lib.spmd_mesh() is not None:
+        if layout == "ivjk":    # reported: the shard body runs unfused
+            _fused_path(f.shape, f.dtype, spmd=True)
         return jax.jit(
             lambda f0: jax.lax.fori_loop(
                 0, iters,
@@ -317,6 +396,8 @@ def lbm_run(f: jax.Array, omega: float, iters: int, *,
                                              omega=omega), f0,
             )
         )(f)
+    if layout == "ivjk" and _fused_path(f.shape, f.dtype):
+        return _run_fused(f, omega, iters=iters)
     # Plan outside the jitted loop so an ambient plan_context change shows
     # up as a new static plan instead of being masked by jit's trace cache.
     plan = dispatch.plan_for(f"lbm.{layout}", tuple(f.shape), f.dtype)

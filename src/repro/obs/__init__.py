@@ -34,6 +34,7 @@ from repro.obs.events import (
     CheckpointEvent,
     DegradedEvent,
     Event,
+    LbmPathEvent,
     MeshChangeEvent,
     PagePoolEvent,
     PlanEvent,
@@ -63,6 +64,6 @@ __all__ = [
     "ValidationEvent", "TrainStepEvent", "CheckpointEvent",
     "AdmissionEvent", "BatcherTickEvent", "PagePoolEvent",
     "PreemptionEvent", "RequestAbandonedEvent", "ProfileDriftEvent",
-    "MeshChangeEvent", "ResumeEvent", "DegradedEvent",
+    "MeshChangeEvent", "ResumeEvent", "DegradedEvent", "LbmPathEvent",
     "EVENT_KINDS", "SPAN_NAMES", "span",
 ]

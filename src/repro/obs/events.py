@@ -35,6 +35,9 @@ and a wall-clock timestamp.  The taxonomy mirrors the repo's existing
                                 mode: a straggling step, a transient-step
                                 retry, retired surplus devices, or a
                                 serving page-pool shrink.
+  * ``LbmPathEvent``         -- which path an ``lbm.ivjk`` launch or run
+                                took: the fused pull+collide kernel, or
+                                roll + collision, with the reason.
 
 Events serialize with :meth:`Event.to_record` -- a flat JSON-safe dict
 with ``kind`` and ``ts`` first -- which is exactly what ``JsonlSink``
@@ -65,6 +68,7 @@ __all__ = [
     "MeshChangeEvent",
     "ResumeEvent",
     "DegradedEvent",
+    "LbmPathEvent",
     "EVENT_KINDS",
 ]
 
@@ -353,6 +357,22 @@ class DegradedEvent(Event):
     step: int = -1
 
 
+@dataclasses.dataclass(frozen=True)
+class LbmPathEvent(Event):
+    """The path an ``lbm.ivjk`` launch or ``lbm_run`` call took, chosen
+    from its shape: "fused" (one pull+collide kernel a sweep, the lattice
+    kept in IvJK planes) or "unfused" (propagation by roll, then the
+    collision kernel), with ``reason`` saying why ("" when fused)."""
+
+    kind: ClassVar[str] = "lbm_path"
+
+    kernel: str
+    shape: tuple
+    dtype: str
+    path: str
+    reason: str = ""
+
+
 EVENT_KINDS: dict[str, type[Event]] = {
     cls.kind: cls
     for cls in (
@@ -371,5 +391,6 @@ EVENT_KINDS: dict[str, type[Event]] = {
         MeshChangeEvent,
         ResumeEvent,
         DegradedEvent,
+        LbmPathEvent,
     )
 }

@@ -24,7 +24,10 @@ reports, per section:
     resumes (restore step, re-chunked batch), and degraded-mode events
     by reason (stragglers, transient retries, retired surplus devices,
     serving pool shrinks);
-  * profile drift -- swept cells the planner no longer reproduces.
+  * profile drift -- swept cells the planner no longer reproduces;
+  * lbm paths -- ``lbm.ivjk`` launches and runs by the path taken (the
+    fused pull+collide kernel or roll + collision), per shape, with the
+    reasons a shape ran unfused.
 
 Sections with no events still print (zeroed), so the summary shape is
 stable for scraping.  ``--json`` emits the aggregate as one JSON object
@@ -98,6 +101,7 @@ def aggregate(records: list[dict]) -> dict:
                "last_resume_step": None, "invalidated_plans": 0,
                "degraded": 0, "degraded_reasons": {}}
     drift = {"total": 0, "cells": []}
+    lbm = {"total": 0, "fused": 0, "unfused": 0, "by_shape": {}}
 
     for rec in records:
         kind = rec["kind"]
@@ -207,6 +211,17 @@ def aggregate(records: list[dict]) -> dict:
             cell = rec.get("cell", "?")
             if cell not in drift["cells"]:
                 drift["cells"].append(cell)
+        elif kind == "lbm_path":
+            path = "fused" if rec.get("path") == "fused" else "unfused"
+            lbm["total"] += 1
+            lbm[path] += 1
+            shape = "x".join(str(n) for n in rec.get("shape", ()))
+            s = lbm["by_shape"].setdefault(
+                shape, {"fused": 0, "unfused": 0, "reasons": []})
+            s[path] += 1
+            reason = rec.get("reason", "")
+            if reason and reason not in s["reasons"]:
+                s["reasons"].append(reason)
 
     planned = plan["hits"] + plan["misses"]
     plan["hit_rate"] = plan["hits"] / planned if planned else None
@@ -235,6 +250,7 @@ def aggregate(records: list[dict]) -> dict:
         "batcher": batcher,
         "elastic": elastic,
         "profile_drift": drift,
+        "lbm_paths": lbm,
     }
 
 
@@ -324,6 +340,15 @@ def render(summary: dict) -> str:
     lines.append(f"profile drift: {dr['total']}"
                  + (f" (cells: {', '.join(dr['cells'])})"
                     if dr["cells"] else ""))
+
+    lb = summary["lbm_paths"]
+    lines.append(f"lbm paths: {lb['total']} -- {lb['fused']} fused / "
+                 f"{lb['unfused']} unfused")
+    for shape in sorted(lb["by_shape"]):
+        s = lb["by_shape"][shape]
+        lines.append(f"  {shape}: {s['fused']} fused / {s['unfused']} "
+                     f"unfused" + (f" ({'; '.join(s['reasons'])})"
+                                   if s["reasons"] else ""))
     return "\n".join(lines)
 
 

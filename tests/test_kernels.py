@@ -167,6 +167,74 @@ class TestLBM:
             np.asarray(out[:, 3:6, 3:6, 3:6]), np.asarray(f[:, 3:6, 3:6, 3:6])
         )
 
+    # Lattices the fused pull+collide sweep takes: Z a multiple of 128, Y
+    # of 8.  The second holds two lane chunks per z line (the z wrap
+    # crosses between them) and a middle sweep, updated in place, at 3.
+    FUSED = [(19, 4, 8, 128), (19, 6, 16, 256)]
+
+    @staticmethod
+    def _lattice(shape, seed=0):
+        """Equilibrium of a random density and velocity field."""
+        k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
+        rho = 1.0 + 0.05 * jax.random.uniform(k1, shape[1:], jnp.float32)
+        u = 0.02 * jax.random.normal(k2, (3,) + shape[1:], jnp.float32)
+        return lref.equilibrium(rho, u)
+
+    @pytest.mark.parametrize("sweeps", [1, 3])
+    @pytest.mark.parametrize("shape", FUSED, ids=str)
+    def test_fused_run_matches_ref(self, shape, sweeps):
+        f = self._lattice(shape)
+        got = lops.lbm_run(f, 1.2, sweeps)
+        want = f
+        for _ in range(sweeps):
+            want = lref.lbm_step(want, 1.2)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=1e-7)
+
+    def test_fused_in_place_sweeps_on_the_tpu_interpreter(self, monkeypatch):
+        """The middle sweeps overwrite their input plane by plane; the TPU
+        interpreter models that aliasing (the generic one copies), so
+        here a sweep that read input plane 0 back after writing output
+        plane 0 would fail.  A shape of its own: no trace from the generic
+        interpreter is cached for it."""
+        from jax.experimental.pallas import tpu as pltpu
+
+        from repro.kernels.lbm import kernel as lkernel
+
+        monkeypatch.setattr(lkernel, "interpret", pltpu.InterpretParams)
+        f = self._lattice((19, 5, 8, 128), seed=1)
+        got = lops.lbm_run(f, 1.2, 4)
+        want = f
+        for _ in range(4):
+            want = lref.lbm_step(want, 1.2)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=1e-7)
+
+    @pytest.mark.parametrize("shape,masked,path,reason", [
+        (FUSED[0], False, "fused", ""),
+        (FUSED[1], False, "fused", ""),
+        ((19, 8, 8, 8), False, "unfused", "Z 8 not a multiple of 128"),
+        (FUSED[0], True, "unfused", "mask"),
+    ], ids=["fused-128", "fused-256", "z8", "masked"])
+    def test_path_event_names_the_path(self, shape, masked, path, reason):
+        """lbm_run, and a launch with a mask, report the path they take
+        (traced only: the choice is made from the shape)."""
+        from repro import obs
+
+        f = jax.ShapeDtypeStruct(shape, jnp.float32)
+        sink = obs.RingBufferSink(capacity=8)
+        with obs.session(sink):
+            if masked:
+                mask = jax.ShapeDtypeStruct(shape[1:], jnp.bool_)
+                jax.eval_shape(lambda f, m: api.launch(
+                    "lbm.ivjk", f, omega=1.2, mask=m), f, mask)
+            else:
+                jax.eval_shape(lambda f: lops.lbm_run(f, 1.2, 3), f)
+        (ev,) = sink.events("lbm_path")
+        assert (ev.kernel, ev.shape, ev.dtype) == ("lbm.ivjk", shape,
+                                                   "float32")
+        assert (ev.path, ev.reason) == (path, reason)
+
     def test_layout_scores_reproduce_fig7(self):
         """Generic N: ivjk balanced; N % 64 == 0: both ruinous (paper)."""
         best, s = lops.layout_balance_scores(n=100)

@@ -75,7 +75,7 @@ class TestEvents:
                  "validation", "train_step", "checkpoint", "admission",
                  "batcher_tick", "page_pool", "preemption",
                  "request_abandoned", "profile_drift",
-                 "mesh_change", "resume", "degraded"}
+                 "mesh_change", "resume", "degraded", "lbm_path"}
         assert set(events.EVENT_KINDS) == kinds
         for kind, cls in events.EVENT_KINDS.items():
             assert cls.kind == kind
@@ -740,6 +740,24 @@ class TestReport:
         assert "eager device updates 1.25/tick" in text
         empty = report.aggregate([])["batcher"]
         assert empty["queue_wait_p50_s"] is empty["mean_eager_updates"] is None
+
+    def test_lbm_path_section_counts_paths(self):
+        evs = [events.LbmPathEvent(kernel="lbm.ivjk",
+                                   shape=(19, 512, 256, 256),
+                                   dtype="float32", path="fused")] * 2
+        evs += [events.LbmPathEvent(kernel="lbm.ivjk", shape=(19, 8, 8, 8),
+                                    dtype="float32", path="unfused",
+                                    reason=r)
+                for r in ("Z 8 not a multiple of 128", "mask", "mask")]
+        s = report.aggregate([e.to_record() for e in evs])
+        lb = s["lbm_paths"]
+        assert (lb["total"], lb["fused"], lb["unfused"]) == (5, 2, 3)
+        assert lb["by_shape"]["19x8x8x8"]["reasons"] == [
+            "Z 8 not a multiple of 128", "mask"]
+        text = report.render(s)
+        assert "lbm paths: 5 -- 2 fused / 3 unfused" in text
+        assert "19x512x256x256: 2 fused / 0 unfused" in text
+        assert "lbm paths: 0" in report.render(report.aggregate([]))
 
     def test_render_is_stable_when_empty(self):
         text = report.render(report.aggregate([]))

@@ -106,6 +106,27 @@ def test_lbm_collision_keeps_its_name(layout, one_chip, native):
         assert re.match(rf"\s*(ROOT )?%{kernel.KERNEL_NAME}\.\d+ = ", line)
 
 
+def test_lbm_fused_run_compiles_at_cell_size(one_chip, native):
+    """The benchmark's LBM call, 8 sweeps of (19, 512, 256, 256) fp32,
+    compiles to fused pull+collide kernels alone: the planes fit the VMEM
+    limit, and no roll (``concatenate``) or layout copy is left around
+    them."""
+    from repro.core.layout import VMEM_LIMIT_BYTES
+    from repro.kernels.lbm import kernel, ops
+
+    assert kernel.pull_collide_vmem_bytes(256, 2, 4) <= VMEM_LIMIT_BYTES
+    f = jax.ShapeDtypeStruct((19, 512, 256, 256), jnp.float32)
+    hlo = _compile(lambda f: ops.lbm_run(f, 1.2, 8, layout="ivjk"),
+                   one_chip, f)
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3      # first sweep, the loop's, last sweep
+    for line in calls:
+        assert re.match(rf"\s*(ROOT )?%{kernel.KERNEL_NAME}\.\d+ = ", line)
+    assert "concatenate" not in hlo
+    assert not re.search(r"= f32\[[0-9,]+\]\S* (copy|transpose)\(", hlo)
+
+
 @pytest.fixture(scope="module")
 def qwen2():
     from repro.configs import get_config
