@@ -11,6 +11,12 @@ full float32 precision.  No kernel, cache or batching.
 ``init_weights`` makes the weights both sides use, from the seed, in the
 tree the system under test takes: an embedding, a final norm and one
 stage ``s00_dense`` whose leaves stack the layers on their first axis.
+
+Beside the reference, what the benchmark needs to know of this
+configuration: ``model_config``, the system's ``ModelConfig`` for it (the
+one function here that imports the system, and only when called), and
+the model operations that the ``mfu`` readers count (``decode_flops``,
+``train_flops_per_token``).
 """
 from __future__ import annotations
 
@@ -22,6 +28,56 @@ import jax.numpy as jnp
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
 
+
+def model_config(cfg: dict):
+    """The system's model configuration for this config file."""
+    from repro.models.config import ModelConfig
+
+    return ModelConfig(
+        name=cfg["name"], family="dense",
+        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
+        n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg["num_key_value_heads"],
+        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
+        qkv_bias=True, tie_embeddings=cfg["tie_word_embeddings"],
+        rope_theta=cfg["rope_theta"], norm_eps=cfg["rms_norm_eps"],
+        dtype=cfg["torch_dtype"])
+
+
+# ---- model operations (the ``mfu`` readers) --------------------------------
+
+def matmul_params(cfg: dict) -> int:
+    """Weights a token multiplies by, LM head included: q, k, v, o and the
+    gated MLP of every layer, and the (tied) output head."""
+    d, h, kv, f = (cfg["hidden_size"], cfg["num_attention_heads"],
+                   cfg["num_key_value_heads"], cfg["intermediate_size"])
+    hd = d // h
+    per_layer = d * h * hd + 2 * d * kv * hd + h * hd * d + 3 * d * f
+    return cfg["num_hidden_layers"] * per_layer + d * cfg["vocab_size"]
+
+
+def attention_flops(cfg: dict, ctx: int) -> int:
+    """Scores and weighted values of one query token over ``ctx`` keys,
+    all layers."""
+    d = cfg["hidden_size"]
+    return 4 * cfg["num_hidden_layers"] * ctx * d
+
+
+def decode_flops(cfg: dict, rows: int, ctx_sum: int) -> int:
+    """One decode call: ``rows`` live rows whose contexts sum to
+    ``ctx_sum`` keys (padding rows and masked keys are not work)."""
+    return 2 * matmul_params(cfg) * rows + attention_flops(cfg, ctx_sum)
+
+
+def train_flops_per_token(cfg: dict, seq: int) -> float:
+    """Forward and backward of one token of a causal sequence of ``seq``:
+    three times the forward, whose attention sees (seq + 1) / 2 keys on
+    average.  Recomputation under remat is not counted."""
+    fwd = 2 * matmul_params(cfg) + attention_flops(cfg, 1) * (seq + 1) / 2
+    return 3 * fwd
+
+
+# ---- the reference -----------------------------------------------------------
 
 def shapes(cfg: dict) -> dict:
     """The weight tree's shapes."""

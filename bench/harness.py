@@ -11,6 +11,28 @@ a file of its own, found by name:
   bench/metrics/<metric>.py    ``read(ctx: Context) -> float | None``
   bench/peaks.json             the chip's peaks, keyed by ``device_kind``
 
+A workload file names its driver and holds every traffic parameter, and
+``"limits"``: the limit of each number its driver compares with the
+reference (``Check``), one for each name in the driver's ``CHECKS``.
+
+The reference of a configuration that a serving or training cell runs
+provides, with ``cfg`` the configuration file's contents:
+
+  init_weights(key, cfg, dtype)       the weights, in the system's tree
+  logits(w, tokens, cfg, low=False)   (S, vocab) float32, one sequence
+  loss(w, tokens, labels, cfg, low=False)
+                                      mean next-token cross-entropy
+  model_config(cfg)                   the system's ``ModelConfig``
+  decode_flops(cfg, rows, ctx_sum)    model operations of one decode
+                                      call (``decode_step.mfu``)
+  train_flops_per_token(cfg, seq)     forward and backward operations
+                                      per token (``train_step.mfu``)
+
+``low=True`` is the control: the same computation in the precision below
+the configuration's.  The training driver also takes ``cosine_lr`` and
+``adamw_step`` from it.  So a configuration of another architecture is
+added as these two files, with no edit to the drivers or readers.
+
 Nothing here imports JAX at import time.
 """
 from __future__ import annotations
@@ -139,6 +161,12 @@ class Check:
     @property
     def ok(self) -> bool:
         return self.value == self.value and self.value <= self.limit
+
+
+def checks(cell: dict, values: dict) -> list[Check]:
+    """Each number compared, beside its limit from the cell's workload
+    file (``"limits"``)."""
+    return [Check(k, v, cell["limits"][k]) for k, v in values.items()]
 
 
 @dataclasses.dataclass

@@ -1,5 +1,7 @@
-"""Share of the device's busy time spent in the collision kernel; the
-rest of a sweep is propagation and the layout transforms around it."""
+"""Share of the device's busy time spent in the LBM kernel (on one chip
+the fused pull+collide sweep, ``lbm_collide.N``); the rest is what XLA
+runs outside it, such as the driver's own fusions and, where a sweep is
+not fused, propagation and the layout transforms."""
 KERNEL = r'custom_call_target="tpu_custom_call"'
 
 

@@ -1,22 +1,8 @@
 """Glue between the benchmark's files and the system under test: the
-system's model configuration for a configuration file, and the weights
-the benchmark makes for it."""
+weights the benchmark makes for a configuration, checked against the
+tree the system's model expects.  (The model itself is the
+configuration's reference's ``model_config``.)"""
 from __future__ import annotations
-
-
-def model_config(cfg: dict):
-    """The system's model configuration for a Qwen2 config file."""
-    from repro.models.config import ModelConfig
-
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        qkv_bias=True, tie_embeddings=cfg["tie_word_embeddings"],
-        rope_theta=cfg["rope_theta"], norm_eps=cfg["rms_norm_eps"],
-        dtype=cfg["torch_dtype"])
 
 
 def make_weights(run, ref, model):

@@ -14,6 +14,7 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 import costs  # noqa: E402
+import harness  # noqa: E402
 import loadgen  # noqa: E402
 import stats  # noqa: E402
 
@@ -169,22 +170,29 @@ QWEN = {"hidden_size": 896, "num_attention_heads": 14,
         "num_hidden_layers": 24, "vocab_size": 151936}
 
 
+@pytest.fixture(scope="module")
+def qwen2():
+    """Qwen2's model operations live with its reference."""
+    return harness.reference("qwen2-0.5b")
+
+
 def test_lbm_site_bytes():
     assert costs.lbm_site_bytes() == 152
     assert costs.lbm_site_bytes(8) == 304
 
 
-def test_qwen2_matmul_params_by_hand():
+def test_qwen2_matmul_params_by_hand(qwen2):
     per_layer = (896 * 896 + 2 * 896 * 128 + 896 * 896 + 3 * 896 * 4864)
-    assert costs.matmul_params(QWEN) == 24 * per_layer + 896 * 151936
-    assert costs.matmul_params(QWEN) == 493_961_216
+    assert qwen2.matmul_params(QWEN) == 24 * per_layer + 896 * 151936
+    assert qwen2.matmul_params(QWEN) == 493_961_216
+    assert qwen2.matmul_params(harness.config("qwen2-0.5b")) == 493_961_216
 
 
-def test_decode_and_train_flops_by_hand():
+def test_decode_and_train_flops_by_hand(qwen2):
     n = 493_961_216
-    assert costs.decode_flops(QWEN, 3, 100) == 2 * n * 3 + 4 * 24 * 100 * 896
+    assert qwen2.decode_flops(QWEN, 3, 100) == 2 * n * 3 + 4 * 24 * 100 * 896
     fwd = 2 * n + 4 * 24 * 896 * (4096 + 1) / 2
-    assert costs.train_flops_per_token(QWEN, 4096) == pytest.approx(3 * fwd)
+    assert qwen2.train_flops_per_token(QWEN, 4096) == pytest.approx(3 * fwd)
 
 
 def test_xent_and_roofline_time():
@@ -192,3 +200,10 @@ def test_xent_and_roofline_time():
     assert costs.xent_bytes(2, 10) == 2 * 10 * 4 + 16
     assert costs.min_seconds(peak, bytes_=2e9, flops=1e12) == 2.0
     assert costs.min_seconds(peak, bytes_=1e8, flops=3e12) == 3.0
+
+
+def test_costs_hold_nothing_of_a_model():
+    """What depends on the architecture is the configuration's own."""
+    public = {n for n in vars(costs) if not n.startswith("_")}
+    assert public == {"annotations", "LBM_Q", "lbm_site_bytes",
+                      "min_seconds", "xent_bytes", "xent_flops"}

@@ -73,10 +73,11 @@ def test_lbm_altered_answer_is_not_correct(monkeypatch):
 def test_lbm_control_is_not_correct():
     """The reference in bfloat16 in the program's place reads above the
     limit the program is held to."""
-    drv = harness.driver("lbm")
-    res = measure(*lbm_cell(), control=True)
-    assert res["info"]["control"] > drv.LIMIT_REL_ERR
-    assert res["checks"]["lbm_max_rel_err"]["value"] <= drv.LIMIT_REL_ERR
+    cell, cfg = lbm_cell()
+    limit = cell["limits"]["lbm_max_rel_err"]
+    res = measure(cell, cfg, control=True)
+    assert res["info"]["control"] > limit
+    assert res["checks"]["lbm_max_rel_err"]["value"] <= limit
 
 
 # ---- serving ---------------------------------------------------------------
@@ -118,26 +119,17 @@ def test_serving_altered_token_is_not_correct(monkeypatch):
 
 
 def test_serving_control_is_not_correct():
-    drv = harness.driver("serve")
-    res = measure(*chat_cell(), control=True)
-    assert res["info"]["control"] > drv.LIMIT_LOGIT_GAP
-    assert res["checks"]["served_logit_gap"]["value"] <= drv.LIMIT_LOGIT_GAP
+    cell, cfg = chat_cell()
+    limit = cell["limits"]["served_logit_gap"]
+    res = measure(cell, cfg, control=True)
+    assert res["info"]["control"] > limit
+    assert res["checks"]["served_logit_gap"]["value"] <= limit
 
 
 # ---- training --------------------------------------------------------------
 
-def prepared(name, **entry):
-    """A cell whose files are here but which ``BENCHMARK.json`` does not
-    list yet (PERF.md, Open questions)."""
-    spec = harness.benchmark()
-    if name not in [w["name"] for w in spec["workloads"]]:
-        spec["workloads"].append({"name": name, **entry})
-    return harness.cell(name, spec)
-
-
 def train_cell(**sizes):
-    cell = prepared("qwen2-0.5b.train_4k", config="qwen2-0.5b",
-                    traffic="train_4k", chips=1)
+    cell = harness.cell("qwen2-0.5b.train_4k", harness.benchmark())
     cell["training"].update(seq_len=sizes.pop("seq_len", 128), global_batch=2)
     cfg = harness.config("qwen2-0.5b")
     cfg.update(hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
@@ -171,14 +163,14 @@ def test_training_state_left_unchanged_is_not_correct(monkeypatch):
 def test_training_control_is_not_correct():
     """The reference with float8 products in the program's place reads
     above a limit the program is held to."""
-    drv = harness.driver("train")
     # Deep enough for float8's error to build up as it does at 24 layers.
-    res = measure(*train_cell(
+    cell, cfg = train_cell(
         seq_len=256, hidden_size=256, num_attention_heads=4,
-        intermediate_size=1024, num_hidden_layers=12, vocab_size=4096),
-        control=True)
+        intermediate_size=1024, num_hidden_layers=12, vocab_size=4096)
+    res = measure(cell, cfg, control=True)
     ctl = res["info"]["control"]
-    assert any(ctl[k] > limit for k, limit in drv.LIMITS.items()), ctl
+    assert any(ctl[k] > limit for k, limit in cell["limits"].items()
+               if k in ctl), ctl
     assert res["correct"], res["checks"]
 
 

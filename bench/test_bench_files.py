@@ -1,7 +1,9 @@
 """Every file the benchmark finds by name loads, ``BENCHMARK.json`` keeps
-to its format, and a cell added as files alone is picked up."""
+to its format, and a cell or a configuration added as files alone is
+picked up."""
 from __future__ import annotations
 
+import dataclasses
 import json
 import os
 import re
@@ -21,6 +23,8 @@ SPEC = harness.benchmark()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 CELLS = [w["name"] for w in SPEC["workloads"]]
+WORKLOAD_FILES = sorted(p.stem for p in (harness.BENCH / "workloads").glob(
+    "*.json"))
 
 
 def test_top_level_keys_and_command():
@@ -139,6 +143,118 @@ def test_a_cell_added_as_files_is_picked_up(tmp_path):
                                       copy.per_layer_metrics(
                                           spec2, "lbm-d3q19.256x256x256")}
     assert callable(copy.driver(cell["driver"]).run)
+
+
+@pytest.mark.parametrize("name", WORKLOAD_FILES)
+def test_workload_files_state_their_limits(name):
+    """Listed or prepared, each workload file gives a limit for every
+    number its driver compares, and for no other."""
+    spec = harness.read_json(harness.BENCH / "workloads" / f"{name}.json")
+    limits = spec["limits"]
+    assert set(limits) == set(harness.driver(spec["driver"]).CHECKS)
+    assert all(isinstance(v, (int, float)) and v >= 0
+               for v in limits.values())
+
+
+def test_qwen2_model_config_field_for_field():
+    """Qwen2's mapping, moved into its reference, builds the model the
+    harness built before it moved."""
+    from repro.models.config import ModelConfig
+
+    want = ModelConfig(
+        name="qwen2-0.5b", family="dense", n_layers=24, d_model=896,
+        n_heads=14, n_kv_heads=2, d_ff=4864, vocab_size=151936,
+        qkv_bias=True, tie_embeddings=True, rope_theta=1000000.0,
+        norm_eps=1e-06, dtype="bfloat16")
+    cfg = harness.config("qwen2-0.5b")
+    got = harness.reference("qwen2-0.5b").model_config(cfg)
+    assert dataclasses.asdict(got) == dataclasses.asdict(want)
+
+
+HYBRID_CONFIG = {
+    "name": "tiny-hybrid", "source": "a test", "reduced": [],
+    "num_hidden_layers": 2, "hidden_size": 64, "num_attention_heads": 4,
+    "intermediate_size": 128, "vocab_size": 256, "state_size": 16,
+    "mamba_headdim": 16, "hybrid_period": 2}
+
+HYBRID_REFERENCE = '''"""A Mamba2 stack with a shared attention block, added as files.
+
+A test's stand-in: its logits are the system's own forward pass, so a
+run shows the path a configuration takes, not that the model is right.
+"""
+import jax.numpy as jnp
+
+
+def model_config(cfg):
+    from repro.models.config import ModelConfig
+
+    return ModelConfig(
+        name=cfg["name"], family="hybrid",
+        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
+        n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg["num_attention_heads"],
+        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
+        ssm_state=cfg["state_size"], ssm_head_dim=cfg["mamba_headdim"],
+        shared_attn_period=cfg["hybrid_period"])
+
+
+def _model(cfg):
+    from repro.models import build_model
+
+    return build_model(model_config(cfg))
+
+
+def init_weights(key, cfg, dtype=jnp.bfloat16):
+    """The served tree; the SSM's decay, skip and step bias stay float32."""
+    return _model(cfg).init(key)
+
+
+def logits(w, tokens, cfg, low=False):
+    return _model(cfg).forward(w, tokens[None])[0][0].astype(jnp.float32)
+'''
+
+
+def test_a_configuration_added_as_files_is_picked_up(tmp_path, monkeypatch):
+    """A later PR adds a configuration of another family with files
+    alone: ``configs/<name>.json``, a reference with ``model_config``, and
+    a cell.  The copied harness serves it through the serving driver
+    (model, weights from the seed, paged batcher, the check) with no edit
+    to any file it already has."""
+    import jax
+
+    shutil.copytree(BENCH, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__", "testdata"))
+    bench = tmp_path / "bench"
+    (bench / "configs" / "tiny-hybrid.json").write_text(
+        json.dumps(HYBRID_CONFIG))
+    (bench / "configs" / "tiny-hybrid.py").write_text(HYBRID_REFERENCE)
+    wl = json.loads((bench / "workloads" / "qwen2-0.5b.chat.json")
+                    .read_text())
+    wl["config"] = "tiny-hybrid"
+    wl["traffic_mix"].update(
+        rate_per_s=8.0, slots=4, max_len=64, lead_s=0.2, drain_s=30.0,
+        prompt_len={"median": 10, "sigma": 0.5, "min": 3, "max": 30},
+        output_len={"median": 8, "sigma": 0.5, "min": 3, "max": 12})
+    (bench / "workloads" / "tiny-hybrid.chat.json").write_text(json.dumps(wl))
+    spec = json.loads(json.dumps(SPEC))
+    spec["workloads"].append({"name": "tiny-hybrid.chat",
+                              "config": "tiny-hybrid", "traffic": "chat",
+                              "chips": 1, "why": "a hybrid model served"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+
+    copy = harness.load_module(bench / "harness.py", "bench_harness_copy")
+    monkeypatch.setitem(sys.modules, "harness", copy)   # the drivers' import
+    monkeypatch.setattr(copy, "peaks", lambda kind, bench_dir=None: {})
+    spec = copy.benchmark()
+    cell = copy.cell("tiny-hybrid.chat", spec)
+    cfg = copy.config("tiny-hybrid")
+    mc = copy.reference("tiny-hybrid").model_config(cfg)
+    assert [k for k, _ in mc.stages()] == ["mamba", "shared_attn"]
+    runner = copy.load_module(bench / "run.py", "bench_run_copy")
+    res = runner.measure(copy, spec, cell, cfg, jax.devices()[:1],
+                         seed=2**33 + 5, seconds=0.3, trace=False)
+    assert res["correct"], res["checks"]
+    assert res["attempted"] >= 1 and res["failed"] == 0
 
 
 def run_cli(root, env_extra=None):
