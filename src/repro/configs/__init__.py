@@ -56,6 +56,8 @@ def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
         kw["capacity_factor"] = 4.0
     if cfg.family == "hybrid":
         kw["shared_attn_period"] = 2
+        kw["hybrid_layer_ids"] = ()
+        kw["adapter_rank"] = min(cfg.adapter_rank, 8)
         kw["ssm_state"] = 16
         kw["ssm_head_dim"] = 32
     if cfg.family == "ssm" and cfg.slstm_every:

@@ -143,14 +143,16 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 # Attention (GQA, optional qk-norm / bias / softcap / cross)
 # ---------------------------------------------------------------------------
 
-def attention_defs(cfg: ModelConfig) -> dict:
-    d, h, kh, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd
+def attention_defs(cfg: ModelConfig, d_in: int | None = None) -> dict:
+    """Projections from ``d_in`` (default d_model) inputs back to d_model."""
+    d, h, kh, hd = d_in or cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd
     dt = cfg.adtype
     defs = {
         "wq": ParamDef((d, h, hd), ("embed", "heads", "head_dim"), dtype=dt),
         "wk": ParamDef((d, kh, hd), ("embed", "kv_heads", "head_dim"), dtype=dt),
         "wv": ParamDef((d, kh, hd), ("embed", "kv_heads", "head_dim"), dtype=dt),
-        "wo": ParamDef((h, hd, d), ("heads", "head_dim", "embed"), dtype=dt),
+        "wo": ParamDef((h, hd, cfg.d_model), ("heads", "head_dim", "embed"),
+                       dtype=dt),
     }
     if cfg.qkv_bias:
         defs["bq"] = ParamDef((h, hd), ("heads", "head_dim"), init="zeros", dtype=dt)
@@ -179,6 +181,12 @@ def _project_qkv(p: dict, x: jax.Array, x_kv: jax.Array, cfg: ModelConfig):
     return q, k, v
 
 
+def _score_denom(cfg: ModelConfig, hd: int) -> jax.Array:
+    """Scores are divided by the root of this: the head width, or half of
+    it in zamba2's shared blocks (softmax scale (hd / 2) ** -0.5)."""
+    return jnp.asarray(hd / 2 if cfg.family == "hybrid" else hd, jnp.float32)
+
+
 def _gqa_scores(q: jax.Array, k: jax.Array, cfg: ModelConfig) -> jax.Array:
     """q: (B,Sq,H,D), k: (B,Sk,KH,D) -> scores (B,KH,G,Sq,Sk) in fp32."""
     b, sq, h, dhd = q.shape
@@ -186,7 +194,7 @@ def _gqa_scores(q: jax.Array, k: jax.Array, cfg: ModelConfig) -> jax.Array:
     g = h // kh
     qg = q.reshape(b, sq, kh, g, dhd)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k).astype(jnp.float32)
-    scores = scores / jnp.sqrt(jnp.asarray(dhd, jnp.float32))
+    scores = scores / jnp.sqrt(_score_denom(cfg, dhd))
     if cfg.attn_softcap:
         cap = cfg.attn_softcap
         scores = cap * jnp.tanh(scores / cap)
@@ -227,7 +235,7 @@ def _chunked_gqa(q: jax.Array, k: jax.Array, v: jax.Array, cfg: ModelConfig,
         kv_pos = jnp.pad(kv_pos, ((0, 0), (0, pad)), constant_values=-1)
     nk = (sk + pad) // block
     qg = q.reshape(b, sq, kh, g, d).transpose(0, 2, 3, 1, 4).astype(jnp.float32)
-    qg = qg / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    qg = qg / jnp.sqrt(_score_denom(cfg, d))
     kb = k.reshape(b, nk, block, kh, d).transpose(1, 0, 3, 2, 4)  # (nk,B,KH,L,D)
     vb = v.reshape(b, nk, block, kh, d).transpose(1, 0, 3, 2, 4)
     pb = kv_pos.reshape(b, nk, block).transpose(1, 0, 2)          # (nk,B,L)
@@ -400,12 +408,14 @@ def paged_kv_pool_defs(cfg: ModelConfig, n_pages: int, page_len: int,
 
 
 def _paged_put(pool: jax.Array, new: jax.Array, pages: jax.Array,
-               idx: jax.Array, act: jax.Array) -> jax.Array:
+               idx: jax.Array, act: jax.Array,
+               layer: int | None = None) -> jax.Array:
     """Insert (B, 1, KH, D) at per-row logical position ``idx`` through the
     page table.  ``act`` (B,) masks the write: inactive rows are routed to
     the null page (physical page 0), so a frozen slot's pool state is
-    bit-identical to not having stepped at all."""
-    p = pool.shape[1]
+    bit-identical to not having stepped at all.  ``layer``: ``pool`` is a
+    stacked pool and this layer's pages are written in place in it."""
+    p = pool.shape[1 if layer is None else 2]
     b = new.shape[0]
     idx = jnp.broadcast_to(jnp.asarray(idx, jnp.int32), (b,))
     lp = jnp.clip(idx // p, 0, pages.shape[1] - 1)
@@ -413,15 +423,18 @@ def _paged_put(pool: jax.Array, new: jax.Array, pages: jax.Array,
     live = act > 0
     phys = jnp.where(live, phys, 0)
     off = jnp.where(live, idx % p, 0)
+    if layer is not None:
+        return pool.at[layer, phys, off].set(new[:, 0])
     return pool.at[phys, off].set(new[:, 0])
 
 
-def _paged_view(pool: jax.Array, pages: jax.Array) -> jax.Array:
+def _paged_view(pool: jax.Array, pages: jax.Array,
+                layer: int | None = None) -> jax.Array:
     """Gather (B, max_pages * page_len, KH, D): the dense bshd view of each
     row's page table.  Unmapped table entries read the null page; their
     positions sit beyond the row's written prefix and are masked by the
     caller's ``<= idx`` validity test."""
-    g = pool[pages]                         # (B, MP, P, KH, D)
+    g = pool[pages] if layer is None else pool[layer, pages]  # (B,MP,P,KH,D)
     b, mp, p = g.shape[:3]
     return g.reshape(b, mp * p, *g.shape[3:])
 
@@ -437,11 +450,19 @@ def paged_decode_attention(
     cfg: ModelConfig,
     *,
     use_rope: bool = True,
+    layer: int | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One-token decode against the paged pool: same math as
     ``decode_attention``, with the cache write scattered through the page
     table and the KV view gathered from it.  Returns (out, new_pool_k,
-    new_pool_v)."""
+    new_pool_v).
+
+    ``layer``: the pools are stacked (layers first) and this layer's pages
+    are written in place.  The view is then gathered from the pools as
+    they came in, and the new token's K/V are attended beside it (the same
+    keys: the view's position ``idx`` is masked): the read no longer waits
+    for the write, so the compiler need not copy each written pool into a
+    buffer of its own before the gather and again into the output."""
     b = x.shape[0]
     idx = jnp.broadcast_to(jnp.asarray(idx, jnp.int32), (b,))
     pos = idx[:, None]
@@ -449,6 +470,13 @@ def paged_decode_attention(
     if use_rope:
         q = rope(q, pos, cfg.rope_theta)
         k = rope(k, pos, cfg.rope_theta)
+    if layer is not None:
+        kv_k = _paged_view(pool_k, pages, layer)
+        kv_v = _paged_view(pool_v, pages, layer)
+        pool_k = _paged_put(pool_k, k, pages, idx, act, layer)
+        pool_v = _paged_put(pool_v, v, pages, idx, act, layer)
+        return _attend_beside(q, k, v, kv_k, kv_v, idx, p, cfg,
+                              x.dtype), pool_k, pool_v
     pool_k = _paged_put(pool_k, k, pages, idx, act)
     pool_v = _paged_put(pool_v, v, pages, idx, act)
     kv_k = _paged_view(pool_k, pages)
@@ -461,6 +489,27 @@ def paged_decode_attention(
     probs = jax.nn.softmax(scores, axis=-1)
     out = _gqa_out(probs, kv_v, p, x.dtype)
     return out, pool_k, pool_v
+
+
+def _attend_beside(q, k, v, kv_k, kv_v, idx, p, cfg, dtype):
+    """One query per row over the cached view's positions ``< idx`` and the
+    new token's own (k, v) at ``idx``, in one softmax."""
+    old = _gqa_scores(q, kv_k, cfg)                      # (B,KH,G,1,S)
+    s = kv_k.shape[1]
+    valid = (jnp.arange(s, dtype=jnp.int32)[None, :]
+             < idx[:, None])[:, None, None, None, :]
+    old = jnp.where(valid, old, -1e30)
+    probs = jax.nn.softmax(
+        jnp.concatenate([old, _gqa_scores(q, k, cfg)], axis=-1), axis=-1)
+    kh = k.shape[2]
+    ctx = (jnp.einsum("bhgqk,bkhd->bqhgd", probs[..., :s].astype(v.dtype),
+                      kv_v)
+           + jnp.einsum("bhgqk,bkhd->bqhgd", probs[..., s:].astype(v.dtype),
+                        v))
+    b, sq = ctx.shape[:2]
+    ctx = ctx.reshape(b, sq, kh * ctx.shape[3], ctx.shape[4])
+    out = jnp.einsum("bqhd,hdm->bqm", ctx, p["wo"])
+    return shard(out.astype(dtype), "batch", None, None)
 
 
 # ---------------------------------------------------------------------------
@@ -477,10 +526,32 @@ def mlp_defs(cfg: ModelConfig, d_ff: int | None = None) -> dict:
     }
 
 
-def apply_mlp(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
-    act = jax.nn.silu if cfg.act == "silu" else jax.nn.gelu
+def activation(cfg: ModelConfig):
+    if cfg.act == "silu":
+        return jax.nn.silu
+    return functools.partial(jax.nn.gelu, approximate=cfg.act == "gelu")
+
+
+def lora_defs(cfg: ModelConfig, d_ff: int | None = None) -> dict:
+    """Low-rank adapter on the MLP's gate and up projections:
+    ``x @ lora_a @ lora_b`` adds to the (gate, up) concatenation."""
+    d, f, r = cfg.d_model, d_ff or cfg.d_ff, cfg.adapter_rank
+    return {
+        "lora_a": ParamDef((d, r), ("embed", None), dtype=cfg.adtype),
+        "lora_b": ParamDef((r, 2 * f), (None, "mlp"), dtype=cfg.adtype),
+    }
+
+
+def apply_mlp(p: dict, x: jax.Array, cfg: ModelConfig,
+              lora: dict | None = None) -> jax.Array:
+    act = activation(cfg)
     h = jnp.einsum("bsd,df->bsf", x, p["wi"])
     g = jnp.einsum("bsd,df->bsf", x, p["wg"])
+    if lora is not None:
+        lo = jnp.einsum("bsd,dr->bsr", x, lora["lora_a"])
+        lo = jnp.einsum("bsr,rf->bsf", lo, lora["lora_b"])
+        g = g + lo[..., :g.shape[-1]]
+        h = h + lo[..., g.shape[-1]:]
     h = shard(act(g) * h, "batch", None, "mlp")
     out = jnp.einsum("bsf,fd->bsd", h, p["wo"])
     return shard(out, "batch", None, None)

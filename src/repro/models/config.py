@@ -38,7 +38,7 @@ class ModelConfig:
     logit_softcap: float | None = None   # grok
     kv_cache_layout: Literal["bhsd", "bshd"] = "bhsd"  # paper SS2.4 layout knob
     # mlp
-    act: Literal["silu", "gelu"] = "silu"
+    act: Literal["silu", "gelu", "gelu_exact"] = "silu"   # gelu: tanh form
     # scaling tricks (minicpm mup-like)
     tie_embeddings: bool = False
     embed_scale: float = 1.0
@@ -60,7 +60,15 @@ class ModelConfig:
     ssm_conv: int = 4
     ssm_expand: int = 2
     ssm_head_dim: int = 64
-    shared_attn_period: int = 0          # zamba2: shared attn block every k mamba layers
+    ssm_ngroups: int = 1                 # B/C groups; heads share their group's
+    ssm_dt_min: float = 0.0              # floor of the softplus step (time_step_min)
+    # zamba2 hybrid: shared attention+MLP blocks applied before the mixer
+    # of each layer in ``hybrid_layer_ids`` (blocks cycled over the
+    # applications); ``shared_attn_period`` k is the shorthand k, 2k, ...
+    hybrid_layer_ids: tuple[int, ...] = ()
+    shared_attn_period: int = 0
+    num_mem_blocks: int = 1
+    adapter_rank: int = 0                # per-application LoRA on the MLP's gate/up
     # xLSTM
     slstm_every: int = 0                 # one sLSTM per this many blocks (0 = none)
     # enc-dec (whisper)
@@ -81,7 +89,19 @@ class ModelConfig:
     # ---- derived ---------------------------------------------------------
     @property
     def hd(self) -> int:
-        return self.head_dim or (self.d_model // self.n_heads)
+        if self.head_dim:
+            return self.head_dim
+        # zamba2's shared blocks attend over the 2 * d_model concat
+        width = 2 * self.d_model if self.family == "hybrid" else self.d_model
+        return width // self.n_heads
+
+    @property
+    def hybrid_ids(self) -> tuple[int, ...]:
+        """Layers whose mixer takes a shared block's output (hybrid)."""
+        if self.hybrid_layer_ids:
+            return tuple(self.hybrid_layer_ids)
+        k = self.shared_attn_period
+        return tuple(range(k, self.n_layers + 1, k)) if k else ()
 
     @property
     def adtype(self):
@@ -103,15 +123,17 @@ class ModelConfig:
         if self.family == "moe":
             return [("moe", self.n_layers)]
         if self.family == "hybrid":
+            # A shared application before the first mixer of each run; one
+            # at n_layers (a period that divides the depth) feeds no mixer.
             out: list[tuple[str, int]] = []
-            period = self.shared_attn_period or self.n_layers
-            remaining = self.n_layers
-            while remaining > 0:
-                run = min(period, remaining)
-                out.append(("mamba", run))
-                remaining -= run
-                if remaining > 0 or run == period:
-                    out.append(("shared_attn", 1))
+            prev = 0
+            for i in self.hybrid_ids:
+                if i > prev:
+                    out.append(("mamba", i - prev))
+                out.append(("shared_attn", 1))
+                prev = i
+            if self.n_layers > prev:
+                out.append(("mamba", self.n_layers - prev))
             return out
         if self.family == "ssm":
             if not self.slstm_every:
@@ -179,5 +201,5 @@ class ModelConfig:
                 kw["moe_d_ff"] = upd("moe_d_ff", self.moe_d_ff, "minor")
         # keep per-head width stable: head_dim becomes explicit when heads pad
         if "n_heads" in changes and self.head_dim is None:
-            kw["head_dim"] = self.d_model // self.n_heads
+            kw["head_dim"] = self.hd
         return dataclasses.replace(self, **kw), changes

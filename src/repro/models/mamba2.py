@@ -1,7 +1,12 @@
 """Mamba2 (SSD) block -- chunked parallel training form + O(1) decode.
 
 Used by zamba2 (hybrid).  Dimensions: d_inner = expand * d_model, H heads of
-width P = ssm_head_dim, state width N = ssm_state, single B/C group.
+width P = ssm_head_dim, state width N = ssm_state, and G = ssm_ngroups B/C
+groups: head h reads group h // (H / G).  The step is floored at
+``ssm_dt_min`` and the gated output norm runs over G groups of d_inner / G
+channels (Zamba2's ``Zamba2RMSNormGated``).  The published single in_proj
+(z, xBC, dt) is kept as its separate matrices, and the depthwise conv over
+xBC as its x and BC parts: the same products.
 
 Training uses the chunked state-space-dual form: within a chunk of length L
 the output is an attention-like einsum with a causal decay mask; across
@@ -25,18 +30,18 @@ def mamba_defs(cfg: ModelConfig) -> dict:
     d = cfg.d_model
     dinner = cfg.ssm_expand * d
     h = dinner // cfg.ssm_head_dim
-    n = cfg.ssm_state
+    gn = cfg.ssm_ngroups * cfg.ssm_state
     k = cfg.ssm_conv
     dt = cfg.adtype
     return {
         "wz": ParamDef((d, dinner), ("embed", "mlp"), dtype=dt),
         "wx": ParamDef((d, dinner), ("embed", "mlp"), dtype=dt),
-        "wbc": ParamDef((d, 2 * n), ("embed", None), dtype=dt),
+        "wbc": ParamDef((d, 2 * gn), ("embed", None), dtype=dt),
         "wdt": ParamDef((d, h), ("embed", "heads"), dtype=dt),
         "conv_x": ParamDef((k, dinner), ("conv", "mlp"), scale=0.5, dtype=dt),
         "conv_x_b": ParamDef((dinner,), ("mlp",), init="zeros", dtype=dt),
-        "conv_bc": ParamDef((k, 2 * n), ("conv", None), scale=0.5, dtype=dt),
-        "conv_bc_b": ParamDef((2 * n,), (None,), init="zeros", dtype=dt),
+        "conv_bc": ParamDef((k, 2 * gn), ("conv", None), scale=0.5, dtype=dt),
+        "conv_bc_b": ParamDef((2 * gn,), (None,), init="zeros", dtype=dt),
         "A_log": ParamDef((h,), ("heads",), init="zeros", dtype=jnp.float32),
         "D": ParamDef((h,), ("heads",), init="ones", dtype=jnp.float32),
         "dt_bias": ParamDef((h,), ("heads",), init="zeros", dtype=jnp.float32),
@@ -66,8 +71,33 @@ def _proj(p: dict, u: jax.Array, cfg: ModelConfig):
 
 
 def _split_heads(x: jax.Array, cfg: ModelConfig) -> jax.Array:
-    b, s, dinner = x.shape
-    return x.reshape(b, s, dinner // cfg.ssm_head_dim, cfg.ssm_head_dim)
+    """(..., d_inner) -> (..., G, H / G, P): heads grouped by B/C group."""
+    g, p = cfg.ssm_ngroups, cfg.ssm_head_dim
+    return x.reshape(*x.shape[:-1], g, x.shape[-1] // (g * p), p)
+
+
+def _split_bc(bc: jax.Array, cfg: ModelConfig):
+    """(..., 2 G N) -> B, C, each (..., G, N) in fp32."""
+    g, n = cfg.ssm_ngroups, cfg.ssm_state
+    bc = bc.astype(jnp.float32).reshape(*bc.shape[:-1], 2, g, n)
+    return bc[..., 0, :, :], bc[..., 1, :, :]
+
+
+def _step(dt_pre: jax.Array, p: dict, cfg: ModelConfig) -> jax.Array:
+    """softplus(dt + dt_bias) floored at ``ssm_dt_min``, as (..., G, H/G)."""
+    dt = jnp.maximum(jax.nn.softplus(dt_pre + p["dt_bias"]), cfg.ssm_dt_min)
+    return dt.reshape(*dt.shape[:-1], cfg.ssm_ngroups, -1)
+
+
+def _gated_norm(y: jax.Array, z: jax.Array, scale: jax.Array,
+                cfg: ModelConfig, dtype) -> jax.Array:
+    """RMSNorm of ``y * silu(z)`` over each of the G groups of d_inner / G
+    channels, in fp32, then the scale (Zamba2RMSNormGated)."""
+    yf = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    grp = yf.reshape(*yf.shape[:-1], cfg.ssm_ngroups, -1)
+    grp = grp * jax.lax.rsqrt((grp * grp).mean(-1, keepdims=True)
+                              + cfg.norm_eps)
+    return grp.reshape(yf.shape).astype(dtype) * scale
 
 
 def mamba_forward(p: dict, u: jax.Array, cfg: ModelConfig) -> jax.Array:
@@ -88,57 +118,52 @@ def mamba_forward(p: dict, u: jax.Array, cfg: ModelConfig) -> jax.Array:
         dt_pre = jnp.pad(dt_pre, ((0, 0), (0, pad), (0, 0)))
     sp = s + pad
     nc = sp // l
-    xh = _split_heads(x, cfg).reshape(b, nc, l, -1, pdim)       # (B,nc,L,H,P)
-    bmat = bc[..., :n].reshape(b, nc, l, n).astype(jnp.float32)
-    cmat = bc[..., n:].reshape(b, nc, l, n).astype(jnp.float32)
-    dt = jax.nn.softplus(dt_pre + p["dt_bias"]).reshape(b, nc, l, -1)  # (B,nc,L,H)
-    a = -jnp.exp(p["A_log"])                                     # (H,) negative
-    da = dt * a                                                   # (B,nc,L,H) <= 0
-    cum = jnp.cumsum(da, axis=2)                                  # (B,nc,L,H)
+    xh = _split_heads(x, cfg)                                     # (B,S,G,Hg,P)
+    g, hg = xh.shape[2], xh.shape[3]
+    xh = xh.reshape(b, nc, l, g, hg, pdim)
+    bmat, cmat = _split_bc(bc, cfg)                               # (B,S,G,N)
+    bmat = bmat.reshape(b, nc, l, g, n)
+    cmat = cmat.reshape(b, nc, l, g, n)
+    dt = _step(dt_pre, p, cfg).reshape(b, nc, l, g, hg)           # (B,nc,L,G,Hg)
+    a = -jnp.exp(p["A_log"]).reshape(g, hg)                       # negative
+    da = dt * a                                                   # <= 0
+    cum = jnp.cumsum(da, axis=2)                                  # (B,nc,L,G,Hg)
     xw = (xh.astype(jnp.float32) * dt[..., None])                 # dt_j * x_j
-    nheads = xh.shape[3]
 
     ii = jnp.arange(l)
-    causal = (ii[:, None] >= ii[None, :]).astype(jnp.float32)     # (L,L)
+    causal = (ii[:, None] >= ii[None, :])[None, :, :, None, None]  # (1,L,L,1,1)
 
     # One chunk at a time (lax.scan over chunks, rematted): the decay
     # "attention" tile (B,L,L,H) never exists for more than one chunk --
     # the VMEM-sized working set a TPU SSD kernel would use.
     def chunk_fn(state, inp):
         xw_c, b_c, c_c, cum_c = inp                               # (B,L,...)
-        cb = jnp.einsum("bin,bjn->bij", c_c, b_c)                 # (B,L,L)
-        dec = jnp.exp(cum_c[:, :, None, :] - cum_c[:, None, :, :])
-        att = cb[..., None] * dec * causal[None, :, :, None]      # (B,L,L,H)
-        y = jnp.einsum("bijh,bjhp->bihp", att, xw_c)
-        y = y + jnp.einsum("bin,bhnp->bihp", c_c, state) * jnp.exp(
+        cb = jnp.einsum("bign,bjgn->bijg", c_c, b_c)              # (B,L,L,G)
+        # Decay from j to i, masked before exp (above the diagonal the
+        # difference is positive and would overflow).
+        dec = jnp.exp(jnp.where(causal, cum_c[:, :, None] - cum_c[:, None],
+                                -jnp.inf))                        # (B,L,L,G,Hg)
+        att = cb[..., None] * dec
+        y = jnp.einsum("bijgh,bjghp->bighp", att, xw_c)
+        y = y + jnp.einsum("bign,bghnp->bighp", c_c, state) * jnp.exp(
             cum_c
         )[..., None]
-        dec_last = jnp.exp(cum_c[:, -1:, :] - cum_c)              # (B,L,H)
-        new_state = jnp.exp(cum_c[:, -1, :])[:, :, None, None] * state + (
-            jnp.einsum("bjn,bjh,bjhp->bhnp", b_c, dec_last, xw_c)
+        dec_last = jnp.exp(cum_c[:, -1:] - cum_c)                 # (B,L,G,Hg)
+        new_state = jnp.exp(cum_c[:, -1])[..., None, None] * state + (
+            jnp.einsum("bjgn,bjgh,bjghp->bghnp", b_c, dec_last, xw_c)
         )
         return new_state, y
 
     chunk_fn = jax.checkpoint(chunk_fn)
-    init = jnp.zeros((b, nheads, n, pdim), jnp.float32)
-    xs = (
-        xw.transpose(1, 0, 2, 3, 4),
-        bmat.transpose(1, 0, 2, 3),
-        cmat.transpose(1, 0, 2, 3),
-        cum.transpose(1, 0, 2, 3),
-    )
+    init = jnp.zeros((b, g, hg, n, pdim), jnp.float32)
+    xs = tuple(t.swapaxes(0, 1) for t in (xw, bmat, cmat, cum))
     _, ys = jax.lax.scan(chunk_fn, init, xs)
-    y_sc = ys.transpose(1, 0, 2, 3, 4)                            # (B,nc,L,H,P)
+    y_sc = ys.swapaxes(0, 1)                                      # (B,nc,L,G,Hg,P)
 
-    y = y_sc + p["D"][None, None, None, :, None] * xh.astype(jnp.float32)
-    y = y.reshape(b, sp, -1)[:, :s, :].astype(u.dtype)            # (B,S,d_inner)
-
-    # ---- gate + norm + out ---------------------------------------------------
-    y = y * jax.nn.silu(z)
-    yf = y.astype(jnp.float32)
-    y = (yf * jax.lax.rsqrt((yf * yf).mean(-1, keepdims=True) + cfg.norm_eps)).astype(
-        u.dtype
-    ) * p["gnorm"]
+    dskip = p["D"].reshape(g, hg)[..., None]
+    y = y_sc + dskip * xh.astype(jnp.float32)
+    y = y.reshape(b, sp, -1)[:, :s, :]                            # (B,S,d_inner)
+    y = _gated_norm(y, z, p["gnorm"], cfg, u.dtype)
     return shard(jnp.einsum("bsi,id->bsd", y, p["wo"]), "batch", None, None)
 
 
@@ -156,7 +181,7 @@ def mamba_cache_defs(cfg: ModelConfig, batch: int, n_stack: int) -> dict:
     return {
         "conv_x": ParamDef((n_stack, batch, k - 1, dinner),
                            ("layers", "batch", None, "mlp"), init="zeros", dtype=dt),
-        "conv_bc": ParamDef((n_stack, batch, k - 1, 2 * n),
+        "conv_bc": ParamDef((n_stack, batch, k - 1, 2 * cfg.ssm_ngroups * n),
                             ("layers", "batch", None, None), init="zeros", dtype=dt),
         "ssm": ParamDef((n_stack, batch, h, n, cfg.ssm_head_dim),
                         ("layers", "batch", "heads", "state", None),
@@ -173,28 +198,25 @@ def _conv_step(xt: jax.Array, state: jax.Array, w: jax.Array, b: jax.Array):
 
 def mamba_decode_step(p: dict, cache: dict, u: jax.Array, cfg: ModelConfig):
     """u: (B,1,d). Returns (y, new_cache)."""
-    n = cfg.ssm_state
+    b = u.shape[0]
     z, x, bc, dt_pre = _proj(p, u, cfg)
     x, conv_x = _conv_step(x, cache["conv_x"], p["conv_x"], p["conv_x_b"])
     x = jax.nn.silu(x)
     bc, conv_bc = _conv_step(bc, cache["conv_bc"], p["conv_bc"], p["conv_bc_b"])
     bc = jax.nn.silu(bc)
-    xh = _split_heads(x, cfg)[:, 0].astype(jnp.float32)           # (B,H,P)
-    bmat = bc[:, 0, :n].astype(jnp.float32)                       # (B,N)
-    cmat = bc[:, 0, n:].astype(jnp.float32)
-    dt = jax.nn.softplus(dt_pre[:, 0] + p["dt_bias"])             # (B,H)
-    a = -jnp.exp(p["A_log"])
-    decay = jnp.exp(dt * a)                                       # (B,H)
-    h = cache["ssm"]                                              # (B,H,N,P)
-    h = decay[:, :, None, None] * h + jnp.einsum(
-        "bn,bh,bhp->bhnp", bmat, dt, xh
+    xh = _split_heads(x[:, 0], cfg).astype(jnp.float32)           # (B,G,Hg,P)
+    g, hg = xh.shape[1], xh.shape[2]
+    bmat, cmat = _split_bc(bc[:, 0], cfg)                         # (B,G,N)
+    dt = _step(dt_pre[:, 0], p, cfg)                              # (B,G,Hg)
+    a = -jnp.exp(p["A_log"]).reshape(g, hg)
+    decay = jnp.exp(dt * a)
+    h = cache["ssm"].reshape(b, g, hg, *cache["ssm"].shape[2:])   # (B,G,Hg,N,P)
+    h = decay[..., None, None] * h + jnp.einsum(
+        "bgn,bgh,bghp->bghnp", bmat, dt, xh
     )
-    y = jnp.einsum("bn,bhnp->bhp", cmat, h) + p["D"][None, :, None] * xh
-    y = y.reshape(u.shape[0], 1, -1).astype(u.dtype)
-    y = y * jax.nn.silu(z)
-    yf = y.astype(jnp.float32)
-    y = (yf * jax.lax.rsqrt((yf * yf).mean(-1, keepdims=True) + cfg.norm_eps)).astype(
-        u.dtype
-    ) * p["gnorm"]
+    y = jnp.einsum("bgn,bghnp->bghp", cmat, h) + (
+        p["D"].reshape(g, hg)[..., None] * xh)
+    y = _gated_norm(y.reshape(b, 1, -1), z, p["gnorm"], cfg, u.dtype)
     out = jnp.einsum("bsi,id->bsd", y, p["wo"])
-    return out, {"conv_x": conv_x, "conv_bc": conv_bc, "ssm": h}
+    return out, {"conv_x": conv_x, "conv_bc": conv_bc,
+                 "ssm": h.reshape(cache["ssm"].shape)}

@@ -3,8 +3,16 @@ vlm (prefix-embed) families.
 
 Layers are grouped into homogeneous *stages* (cfg.stages()); each stage is a
 jax.lax.scan over stacked per-layer parameters with an optional remat policy.
-Zamba2's shared attention block is a single parameter set applied at every
-('shared_attn', 1) stage with its own per-application KV cache.
+
+Zamba2 (hybrid) follows the published hybrid layer: at each
+('shared_attn', 1) stage, application i runs shared block i % num_mem_blocks
+(``shared_b<j>``: one parameter set each) on ``concat[x, h0]`` (h0 the token
+embedding): RMSNorm(2d) -> attention over the 2d input -> RMSNorm(d) -> MLP
+with the application's own LoRA on gate/up -> the application's own d x d
+``linear``.  No residual is taken inside the block; its output is added to
+the input of the next layer's Mamba2 mixer only (the residual stream skips
+it).  Each application keeps its own KV cache and its per-application leaves
+live under its stage name.
 
 The module exposes stage-level callables so the roofline harness can lower
 one stage body and multiply by its trip count (XLA's cost_analysis counts a
@@ -47,15 +55,12 @@ def block_defs(cfg: ModelConfig, kind: str) -> Tree:
         }
     if kind == "mamba":
         return {"ln1": blocks.norm_defs(cfg), "mamba": mamba2.mamba_defs(cfg)}
-    if kind == "shared_attn":
-        return {
-            "win": ParamDef((2 * cfg.d_model, cfg.d_model), ("embed", "embed"),
-                            dtype=cfg.adtype),
-            "ln1": blocks.norm_defs(cfg),
-            "attn": blocks.attention_defs(cfg),
-            "ln2": blocks.norm_defs(cfg),
-            "mlp": blocks.mlp_defs(cfg),
-        }
+    if kind == "shared_attn":          # one application's own leaves
+        defs = {"linear": ParamDef((cfg.d_model, cfg.d_model),
+                                   ("embed", None), dtype=cfg.adtype)}
+        if cfg.adapter_rank:
+            defs.update(blocks.lora_defs(cfg))
+        return defs
     if kind == "mlstm":
         return {"ln1": blocks.norm_defs(cfg), "mlstm": xlstm.mlstm_defs(cfg)}
     if kind == "slstm":
@@ -63,8 +68,24 @@ def block_defs(cfg: ModelConfig, kind: str) -> Tree:
     raise ValueError(kind)
 
 
+def shared_block_defs(cfg: ModelConfig) -> Tree:
+    """One of zamba2's shared attention+MLP blocks."""
+    d2 = 2 * cfg.d_model
+    return {
+        "ln1": blocks.norm_defs(cfg, d2),
+        "attn": blocks.attention_defs(cfg, d2),
+        "ln2": blocks.norm_defs(cfg),
+        "mlp": blocks.mlp_defs(cfg),
+    }
+
+
 def stage_name(i: int, kind: str) -> str:
     return f"s{i:02d}_{kind}"
+
+
+def shared_name(app: int, cfg: ModelConfig) -> str:
+    """The shared block that application ``app`` runs (blocks cycled)."""
+    return f"shared_b{app % cfg.num_mem_blocks}"
 
 
 def param_defs(cfg: ModelConfig) -> Tree:
@@ -76,14 +97,16 @@ def param_defs(cfg: ModelConfig) -> Tree:
     if not cfg.tie_embeddings:
         tree["lm_head"] = ParamDef((cfg.d_model, cfg.vocab_size),
                                    ("embed", "vocab"), dtype=cfg.adtype)
-    has_shared = False
+    apps = 0
     for i, (kind, count) in enumerate(cfg.stages()):
         if kind == "shared_attn":
-            has_shared = True
-            continue  # single shared subtree added below
-        tree[stage_name(i, kind)] = stack_defs(block_defs(cfg, kind), count)
-    if has_shared:
-        tree["shared_attn"] = block_defs(cfg, "shared_attn")
+            tree[stage_name(i, kind)] = block_defs(cfg, kind)
+            apps += 1
+        else:
+            tree[stage_name(i, kind)] = stack_defs(block_defs(cfg, kind),
+                                                   count)
+    for j in range(min(apps, cfg.num_mem_blocks)):
+        tree[f"shared_b{j}"] = shared_block_defs(cfg)
     return tree
 
 
@@ -109,17 +132,13 @@ def _expert_shards(cfg: ModelConfig) -> int:
 # ---------------------------------------------------------------------------
 
 def _apply_block(kind: str, p: Tree, x: jax.Array, cfg: ModelConfig,
-                 positions: jax.Array, h0: jax.Array | None):
-    """One layer. Returns (x, aux)."""
+                 positions: jax.Array, inject: jax.Array | None = None):
+    """One layer. Returns (x, aux).  ``inject``: a shared block's output,
+    added to a mamba layer's mixer input and not to its residual."""
     aux = jnp.zeros((), jnp.float32)
     rs = jnp.asarray(cfg.residual_scale, x.dtype)
-    if kind in ("dense", "moe", "shared_attn"):
-        if kind == "shared_attn":
-            xin = jnp.concatenate([x, h0], axis=-1)
-            xin = jnp.einsum("bse,ed->bsd", xin, p["win"])
-        else:
-            xin = x
-        h = blocks.apply_norm(p["ln1"], xin, cfg)
+    if kind in ("dense", "moe"):
+        h = blocks.apply_norm(p["ln1"], x, cfg)
         h = blocks.attention(p["attn"], h, cfg, positions=positions)
         x = x + rs * h
         h = blocks.apply_norm(p["ln2"], x, cfg)
@@ -129,7 +148,8 @@ def _apply_block(kind: str, p: Tree, x: jax.Array, cfg: ModelConfig,
             h = blocks.apply_mlp(p["mlp"], h, cfg)
         x = x + rs * h
     elif kind == "mamba":
-        h = blocks.apply_norm(p["ln1"], x, cfg)
+        xin = x if inject is None else x + inject
+        h = blocks.apply_norm(p["ln1"], xin, cfg)
         x = x + rs * mamba2.mamba_forward(p["mamba"], h, cfg)
     elif kind == "mlstm":
         h = blocks.apply_norm(p["ln1"], x, cfg)
@@ -142,25 +162,46 @@ def _apply_block(kind: str, p: Tree, x: jax.Array, cfg: ModelConfig,
     return x, aux
 
 
+def _shared_mlp_out(blk: Tree, app: Tree, h: jax.Array, cfg: ModelConfig
+                    ) -> jax.Array:
+    """The shared block after its attention: RMSNorm(d), the MLP with the
+    application's adapter, the application's ``linear``."""
+    h = blocks.apply_norm(blk["ln2"], h, cfg)
+    h = blocks.apply_mlp(blk["mlp"], h, cfg,
+                         app if cfg.adapter_rank else None)
+    return jnp.einsum("bsd,de->bse", h, app["linear"])
+
+
+def _apply_shared(blk: Tree, app: Tree, x: jax.Array, h0: jax.Array,
+                  cfg: ModelConfig, positions: jax.Array) -> jax.Array:
+    """One shared-block application over a whole sequence: what it adds
+    to the next mixer's input."""
+    h = blocks.apply_norm(blk["ln1"], jnp.concatenate([x, h0], axis=-1), cfg)
+    h = blocks.attention(blk["attn"], h, cfg, positions=positions)
+    return _shared_mlp_out(blk, app, h, cfg)
+
+
 def _stage_scan(kind: str, stage_params: Tree, x: jax.Array, cfg: ModelConfig,
-                positions: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Scan one homogeneous stage over its stacked layers."""
+                positions: jax.Array, inject: jax.Array | None = None
+                ) -> tuple[jax.Array, jax.Array]:
+    """Scan one homogeneous stage over its stacked layers.  ``inject`` goes
+    into the first layer only (it is zero in the carry after it)."""
 
     def body(carry, lp):
-        h, aux = carry
+        h, aux, inj = carry
         h = shard(h, "batch", None, None)
-        h, a = _apply_block(kind, lp, h, cfg, positions, None)
-        return (h, aux + a), None
+        h, a = _apply_block(kind, lp, h, cfg, positions, inj)
+        return (h, aux + a, None if inj is None else jnp.zeros_like(inj)), None
 
     if cfg.remat:
         body = jax.checkpoint(body)
-    carry = (x, jnp.zeros((), jnp.float32))
+    carry = (x, jnp.zeros((), jnp.float32), inject)
     if cfg.unroll:
         count = jax.tree_util.tree_leaves(stage_params)[0].shape[0]
         for i in range(count):
             carry, _ = body(carry, jax.tree.map(lambda a: a[i], stage_params))
-        return carry
-    (x, aux), _ = jax.lax.scan(body, carry, stage_params)
+        return carry[:2]
+    (x, aux, _), _ = jax.lax.scan(body, carry, stage_params)
     return x, aux
 
 
@@ -193,23 +234,19 @@ def forward(params: Tree, tokens: jax.Array, cfg: ModelConfig,
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     h0 = x
     aux_total = jnp.zeros((), jnp.float32)
+    inject, app = None, 0
     for i, (kind, count) in enumerate(cfg.stages()):
         if kind == "shared_attn":
-            fn = functools.partial(_apply_block, kind, cfg=cfg,
-                                   positions=positions)
+            fn = functools.partial(_apply_shared, cfg=cfg, positions=positions)
             if cfg.remat:
-                fn = jax.checkpoint(
-                    lambda pp, hh, hh0: _apply_block(
-                        "shared_attn", pp, hh, cfg, positions, hh0
-                    )
-                )
-                x, aux = fn(params["shared_attn"], x, h0)
-            else:
-                x, aux = _apply_block(kind, params["shared_attn"], x, cfg,
-                                      positions, h0)
-        else:
-            x, aux = _stage_scan(kind, params[stage_name(i, kind)], x, cfg,
-                                 positions)
+                fn = jax.checkpoint(fn)
+            inject = fn(params[shared_name(app, cfg)],
+                        params[stage_name(i, kind)], x, h0)
+            app += 1
+            continue
+        x, aux = _stage_scan(kind, params[stage_name(i, kind)], x, cfg,
+                             positions, inject)
+        inject = None
         aux_total = aux_total + aux
     logits = unembed(params, x, cfg)
     if prefix_embeds is not None:
@@ -370,25 +407,13 @@ def paged_cache_defs(cfg: ModelConfig, batch: int, max_len: int,
 
 
 def _decode_block(kind: str, p: Tree, cache: Tree, x: jax.Array, idx: jax.Array,
-                  cfg: ModelConfig, h0: jax.Array | None,
+                  cfg: ModelConfig, inject: jax.Array | None = None,
                   pages: jax.Array | None = None,
                   act: jax.Array | None = None):
     rs = jnp.asarray(cfg.residual_scale, x.dtype)
-    if kind in ("dense", "moe", "shared_attn"):
-        if kind == "shared_attn":
-            xin = jnp.concatenate([x, h0], axis=-1)
-            xin = jnp.einsum("bse,ed->bsd", xin, p["win"])
-        else:
-            xin = x
-        h = blocks.apply_norm(p["ln1"], xin, cfg)
-        if pages is not None:
-            h, ck, cv = blocks.paged_decode_attention(
-                p["attn"], h, cache["k"], cache["v"], pages, idx, act, cfg
-            )
-        else:
-            h, ck, cv = blocks.decode_attention(
-                p["attn"], h, cache["k"], cache["v"], idx, cfg
-            )
+    if kind in ("dense", "moe"):
+        h = blocks.apply_norm(p["ln1"], x, cfg)
+        h, nc = _decode_attention(p["attn"], h, cache, idx, cfg, pages, act)
         x = x + rs * h
         h = blocks.apply_norm(p["ln2"], x, cfg)
         if kind == "moe":
@@ -396,9 +421,10 @@ def _decode_block(kind: str, p: Tree, cache: Tree, x: jax.Array, idx: jax.Array,
         else:
             h = blocks.apply_mlp(p["mlp"], h, cfg)
         x = x + rs * h
-        return x, {"k": ck, "v": cv}
+        return x, nc
     if kind == "mamba":
-        h = blocks.apply_norm(p["ln1"], x, cfg)
+        xin = x if inject is None else x + inject
+        h = blocks.apply_norm(p["ln1"], xin, cfg)
         h, nc = mamba2.mamba_decode_step(p["mamba"], cache, h, cfg)
         return x + rs * h, nc
     if kind == "mlstm":
@@ -410,6 +436,20 @@ def _decode_block(kind: str, p: Tree, cache: Tree, x: jax.Array, idx: jax.Array,
         h, nc = xlstm.slstm_decode_step(p["slstm"], cache, h, cfg)
         return x + rs * h, nc
     raise ValueError(kind)
+
+
+def _decode_attention(p: Tree, h: jax.Array, cache: Tree, idx: jax.Array,
+                      cfg: ModelConfig, pages: jax.Array | None,
+                      act: jax.Array | None, layer: int | None = None):
+    """One token's attention against the dense cache or the paged pool
+    (``layer``: a stacked pool, read and written in place)."""
+    if pages is not None:
+        h, ck, cv = blocks.paged_decode_attention(
+            p, h, cache["k"], cache["v"], pages, idx, act, cfg, layer=layer)
+    else:
+        h, ck, cv = blocks.decode_attention(p, h, cache["k"], cache["v"],
+                                            idx, cfg)
+    return h, {"k": ck, "v": cv}
 
 
 def decode_step(params: Tree, cache: Tree, tokens: jax.Array, cfg: ModelConfig
@@ -430,36 +470,48 @@ def decode_step(params: Tree, cache: Tree, tokens: jax.Array, cfg: ModelConfig
     if pages is not None:
         new_cache["pages"] = pages
         new_cache["act"] = act
+    inject, app = None, 0
     for i, (kind, count) in enumerate(cfg.stages()):
         nm = stage_name(i, kind)
         if kind == "shared_attn":
-            # single-layer stage: strip the stacked axis of its cache
-            c1 = jax.tree.map(lambda a: a[0], cache[nm])
-            x, nc = _decode_block(kind, params["shared_attn"], c1, x, idx,
-                                  cfg, h0, pages, act)
-            new_cache[nm] = jax.tree.map(lambda a: a[None], nc)
-        else:
-            def body(carry, inp):
-                lp, lc = inp
-                h = carry
-                h, nc = _decode_block(kind, lp, lc, h, idx, cfg, None,
-                                      pages, act)
-                return h, nc
+            # A one-application stage.  Its paged pools stay stacked and
+            # are written in place: slicing one out and stacking it back
+            # copies the whole pool twice.
+            blk = params[shared_name(app, cfg)]
+            c1 = (cache[nm] if pages is not None
+                  else jax.tree.map(lambda a: a[0], cache[nm]))
+            h = blocks.apply_norm(blk["ln1"], jnp.concatenate([x, h0], -1),
+                                  cfg)
+            h, nc = _decode_attention(blk["attn"], h, c1, idx, cfg, pages,
+                                      act, 0 if pages is not None else None)
+            inject = _shared_mlp_out(blk, params[nm], h, cfg)
+            new_cache[nm] = (nc if pages is not None
+                             else jax.tree.map(lambda a: a[None], nc))
+            app += 1
+            continue
 
-            if cfg.unroll:
-                n = jax.tree_util.tree_leaves(cache[nm])[0].shape[0]
-                ncs = []
-                for l in range(n):
-                    x, nc_l = body(
-                        x,
-                        (jax.tree.map(lambda a: a[l], params[nm]),
-                         jax.tree.map(lambda a: a[l], cache[nm])),
-                    )
-                    ncs.append(nc_l)
-                nc = jax.tree.map(lambda *a: jnp.stack(a), *ncs)
-            else:
-                x, nc = jax.lax.scan(body, x, (params[nm], cache[nm]))
-            new_cache[nm] = nc
+        def body(carry, inp):
+            h, inj = carry
+            lp, lc = inp
+            h, nc = _decode_block(kind, lp, lc, h, idx, cfg, inj, pages, act)
+            return (h, None if inj is None else jnp.zeros_like(inj)), nc
+
+        carry = (x, inject)
+        if cfg.unroll:
+            n = jax.tree_util.tree_leaves(cache[nm])[0].shape[0]
+            ncs = []
+            for l in range(n):
+                carry, nc_l = body(
+                    carry,
+                    (jax.tree.map(lambda a: a[l], params[nm]),
+                     jax.tree.map(lambda a: a[l], cache[nm])),
+                )
+                ncs.append(nc_l)
+            nc = jax.tree.map(lambda *a: jnp.stack(a), *ncs)
+        else:
+            carry, nc = jax.lax.scan(body, carry, (params[nm], cache[nm]))
+        x, inject = carry[0], None
+        new_cache[nm] = nc
     logits = unembed(params, x, cfg)
     return logits, new_cache
 

@@ -217,6 +217,9 @@ class BatcherTickEvent(Event):
     the step program since the previous tick: page-table writes (one per
     page claimed, one per slot's pages released) and slot resets (one
     per cache leaf with a batch axis).  Each is a dispatch of its own.
+    ``state_reset_bytes`` is what those slot resets rewrote: each one
+    writes its whole leaf (conv and SSM state of every slot, for a
+    hybrid model), not just the slot's row.
     """
 
     kind: ClassVar[str] = "batcher_tick"
@@ -230,6 +233,7 @@ class BatcherTickEvent(Event):
     pad_slots: int
     queue_depth: int
     eager_updates: int = 0
+    state_reset_bytes: int = 0
 
 
 @dataclasses.dataclass(frozen=True)

@@ -93,6 +93,7 @@ def aggregate(records: list[dict]) -> dict:
              "checkpoint_restores": 0}
     batcher = {"admissions": 0, "max_queue_depth": 0, "ticks": 0,
                "sum_waste_frac": 0.0, "sum_eager_updates": 0,
+               "sum_state_reset_bytes": 0,
                "waits_s": [], "page_ticks": 0,
                "sum_page_util": 0.0, "peak_page_util": None,
                "preemptions": 0, "preempt_reasons": {},
@@ -176,6 +177,8 @@ def aggregate(records: list[dict]) -> dict:
                 rec.get("pad_slots", 0))
             batcher["sum_waste_frac"] += waste / padded
             batcher["sum_eager_updates"] += int(rec.get("eager_updates", 0))
+            batcher["sum_state_reset_bytes"] += int(
+                rec.get("state_reset_bytes", 0))
             batcher["max_queue_depth"] = max(
                 batcher["max_queue_depth"], int(rec.get("queue_depth", 0)))
         elif kind == "page_pool":
@@ -236,6 +239,9 @@ def aggregate(records: list[dict]) -> dict:
     batcher["queue_wait_max_s"] = max(waits) if waits else None
     batcher["mean_eager_updates"] = (
         batcher["sum_eager_updates"] / batcher["ticks"]
+        if batcher["ticks"] else None)
+    batcher["mean_state_reset_bytes"] = (
+        batcher["sum_state_reset_bytes"] / batcher["ticks"]
         if batcher["ticks"] else None)
     batcher["mean_page_util"] = (
         batcher["sum_page_util"] / batcher["page_ticks"]
@@ -311,7 +317,8 @@ def render(summary: dict) -> str:
         f"  queue wait p50 {_fmt(ba['queue_wait_p50_s'])}s, "
         f"p95 {_fmt(ba['queue_wait_p95_s'])}s, "
         f"max {_fmt(ba['queue_wait_max_s'])}s; eager device updates "
-        f"{_fmt(ba['mean_eager_updates'])}/tick")
+        f"{_fmt(ba['mean_eager_updates'])}/tick, slot resets rewrote "
+        f"{_fmt(ba['mean_state_reset_bytes'])} B/tick")
     util = ba["mean_page_util"]
     reasons = "; ".join(f"{r}: {n}" for r, n in
                         sorted(ba["preempt_reasons"].items()))

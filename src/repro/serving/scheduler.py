@@ -189,6 +189,14 @@ class ContinuousBatcher:
         self._eager_updates = 0
         self._reset_leaves = sum(
             ax >= 0 for ax in jax.tree.leaves(self._batch_axes))
+        # Each reset rewrites its whole leaf (an eager ``.at[slot].set``
+        # of a non-donated array): these bytes per admission, reported
+        # on BatcherTickEvent.state_reset_bytes.
+        self._state_reset_bytes = 0
+        self._reset_bytes = sum(
+            c.nbytes for c, ax in zip(jax.tree.leaves(self.cache),
+                                      jax.tree.leaves(self._batch_axes))
+            if ax >= 0)
 
     # ---- layout planning ---------------------------------------------------
     def _batch_plan(self, rows: int):
@@ -396,6 +404,7 @@ class ContinuousBatcher:
                     self._slot_seq[s] = self._seq
                     self.cache = self._reset_slot(self.cache, s)
                     self._eager_updates += self._reset_leaves
+                    self._state_reset_bytes += self._reset_bytes
                     admitted.append(req.rid)
                     if obs.enabled():
                         obs.emit(obs.AdmissionEvent(
@@ -487,8 +496,9 @@ class ContinuousBatcher:
 
     def _emit_tick_events(self) -> None:
         """The tick's occupancy and pool events; starts the next count of
-        eager updates whether or not anyone listens."""
+        eager updates and reset bytes whether or not anyone listens."""
         eager, self._eager_updates = self._eager_updates, 0
+        reset_bytes, self._state_reset_bytes = self._state_reset_bytes, 0
         if not obs.enabled():
             return
         # Packing waste is the tick's dead rows: slots with no tenant
@@ -504,7 +514,8 @@ class ContinuousBatcher:
             slots=self.slots, padded_slots=self.padded_slots,
             free_slots=self.slots - n_prefill - n_decode,
             pad_slots=self.padded_slots - self.slots,
-            queue_depth=len(self.queue), eager_updates=eager))
+            queue_depth=len(self.queue), eager_updates=eager,
+            state_reset_bytes=reset_bytes))
         if self.pages is not None:
             obs.emit(obs.PagePoolEvent(
                 tick=self.ticks, used_pages=self.pages.used_pages,

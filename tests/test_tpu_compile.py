@@ -10,6 +10,7 @@ TPU compiler library, and it keeps it until it exits.
 """
 from __future__ import annotations
 
+import importlib.util
 import re
 
 import jax
@@ -159,3 +160,31 @@ def test_qwen2_loss_gradient_compiles(qwen2, one_chip, native):
              "labels": jax.ShapeDtypeStruct((8, 128), jnp.int32)}
     hlo = _compile(jax.grad(model.loss), one_chip, params, batch)
     assert "tpu_custom_call" in hlo
+
+
+def test_zamba2_paged_decode_step_compiles(one_chip, native):
+    """The zamba2-7b chat cell's decode tick at published widths (layers
+    0-11, 32 slots, 1024 positions in 16-token pages).  Its shared blocks'
+    pools are written in place and read as they came in: with a pool
+    copied out and back the temporaries were 4.8 GB, over the 13 GB the
+    cell may hold in all."""
+    import json
+    import pathlib
+
+    from repro.models import build_model
+    from repro.models.params import abstract_params
+    from repro.parallel import steps as steps_lib
+
+    bench = pathlib.Path(__file__).resolve().parents[1] / "bench"
+    spec = importlib.util.spec_from_file_location(
+        "zamba2_7b_ref", bench / "configs" / "zamba2-7b.py")
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    cfg = json.loads((bench / "configs" / "zamba2-7b.json").read_text())
+    model = build_model(ref.model_config(cfg))
+    cache = abstract_params(model.paged_cache_defs(32, 1024, 32 * 64 + 1, 16))
+    tokens = jax.ShapeDtypeStruct((32, 1), jnp.int32)
+    compiled = jax.jit(steps_lib.make_decode_step(model)).lower(
+        *_on(one_chip, (model.abstract_params(), cache, tokens))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
