@@ -18,14 +18,17 @@ TPU port of the two layouts:
 ``collide_soa`` and ``collide_ivjk`` are the site-local collision of one
 layout; on their path ops.py propagates by ``jnp.roll`` and transposes the
 lattice into and out of the layout around every sweep.  That path serves
-``lbm.soa``, the SPMD shard body, masked launches and lattices the fused
-sweep cannot tile.  ``pull_collide_ivjk`` is a whole sweep of ``lbm.ivjk``
-on one device: it pulls each population from its upwind neighbour across
-X planes held in VMEM, shifts y and z in VMEM, and collides, reading and
-writing the lattice once.  Between the sweeps of a run the lattice stays
-in IvJK planes; the first sweep reads the (Q, X, Y, Z) lattice and the
-last writes it.  All of them share the same arithmetic (``_moments``,
-``_feq``) and the same name in a profile (``KERNEL_NAME``).
+``lbm.soa``, masked launches and lattices (or shard stripes) the fused
+sweep cannot tile, and collides the SPMD shard body's two boundary
+planes.  ``pull_collide_ivjk`` is a whole sweep of ``lbm.ivjk``: it
+pulls each population from its upwind neighbour across X planes held in
+VMEM, shifts y and z in VMEM, and collides, reading and writing the
+lattice once.  On one device the lattice stays in IvJK planes between
+the sweeps of a run; the first sweep reads the (Q, X, Y, Z) lattice and
+the last writes it.  Under a mesh each sweep of a shard's stripe reads
+and writes the (Q, X, Y, Z) stripe.  All of them share the same
+arithmetic (``_moments``, ``_feq``) and the same name in a profile
+(``KERNEL_NAME``).
 """
 from __future__ import annotations
 

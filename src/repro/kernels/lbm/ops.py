@@ -4,15 +4,15 @@ accounting.
 ``lbm.soa`` and ``lbm.ivjk`` register as separate kernels (the paper's Fig. 7
 layout comparison is a *planning* decision, so it lives in the kernel name).
 
-Two paths run a sweep on one device:
+Two paths run a sweep:
 
   * fused (``lbm.ivjk`` only): one ``kernel.pull_collide_ivjk`` per sweep,
-    propagation and collision together, the lattice kept in IvJK planes
-    between the sweeps of ``lbm_run``.  Taken when the lattice is on a
-    single device (no SPMD mesh), has no mask, is 32-bit, Z is a multiple
-    of 128, Y of 8, and its X planes fit VMEM (``_unfused_reason``).
-    ``lbm_run`` and every ``lbm.ivjk`` launch report the path they take as
-    an ``obs`` ``LbmPathEvent``.
+    propagation and collision together.  Taken when the lattice (under a
+    mesh: the shard's local stripe) has no mask, is 32-bit, Z is a
+    multiple of 128, Y of 8, and its X planes fit VMEM
+    (``_unfused_reason``).  On one device the lattice stays in IvJK planes
+    between the sweeps of ``lbm_run``.  Every ``lbm.ivjk`` launch, run and
+    shard body reports the path it takes as an ``obs`` ``LbmPathEvent``.
   * unfused (``lbm.soa``, and ``lbm.ivjk`` otherwise): propagation by
     ``jnp.roll`` (``ref.propagate``), then the planned Pallas collision.
     Pad multiples and block shapes come from the planner's VMEM-budget
@@ -27,10 +27,14 @@ Under an SPMD mesh the lattice shards its X axis over the data axis with
 5 have c_x = -1 and 9 never cross an X cut, so one streaming step
 ppermutes two (5, 1, Y, Z) slabs around the (periodic) ring instead of
 replicating the whole lattice.  The shard body is overlapped
-(docs/OVERLAP.md): slabs are issued first, the interior planes (which pull
-only from locally-resident planes) propagate+collide while they fly, and
-only the two boundary planes read the arriving slabs.  It runs the
-unfused path.
+(docs/OVERLAP.md): slabs are issued first, the planes that pull only
+from locally-resident planes propagate+collide while they fly, and only
+the two boundary planes read the arriving slabs.  On the fused path that
+work is one fused sweep of the whole stripe, periodic within it, whose
+two boundary planes are then overwritten in place; the unfused path
+(masks, multi-axis X, stripes under 3 planes, and what the kernel cannot
+tile) collides the interior planes and concatenates the boundary planes
+around them.
 """
 from __future__ import annotations
 
@@ -114,14 +118,19 @@ def _step_ivjk(f, omega, mask, *, plan):
 _FUSED_VMEM = VMEM_LIMIT_BYTES * 3 // 4
 
 
-def _unfused_reason(shape, dtype, *, masked=False, spmd=False) -> str:
+def _unfused_reason(shape, dtype, *, masked=False, x_axes=()) -> str:
     """Why a sweep of ``lbm.ivjk`` at ``shape`` cannot be the fused
     pull+collide kernel ("" when it can).  The fused kernel pulls across
-    whole 128-lane z chunks and 8-row y strips of a single device's
-    lattice, with three X planes and the one in flight in VMEM."""
-    _, _, y, z = shape
-    if spmd:
-        return "spmd mesh"
+    whole 128-lane z chunks and 8-row y strips, with three X planes and the
+    one in flight in VMEM.  Under a mesh ``shape`` is the shard's local
+    stripe and ``x_axes`` the mesh axes its X is sharded over: the shard
+    body finishes the stripe's two boundary planes from the halo slabs of
+    one ring, and needs a plane between them that the kernel gets right."""
+    _, xl, y, z = shape
+    if len(x_axes) > 1:
+        return "multi-axis X"
+    if x_axes and xl < 3:
+        return "stripe under 3 planes"
     if masked:
         return "mask"
     if jnp.dtype(dtype).itemsize != 4:
@@ -178,6 +187,13 @@ def _roll_yz(a, v: int):
     return jnp.roll(a, shift=(cy, cz), axis=(-2, -1))
 
 
+def _directions(slab, dirs):
+    """The populations ``dirs`` of a (Q, planes, Y, Z) slab, by static
+    slices: an index array over the whole stripe has XLA gather whole
+    stripes before it slices out the edge planes."""
+    return jnp.concatenate([slab[v:v + 1] for v in dirs], axis=0)
+
+
 def _halo_exchange_x(f, x_axes, n_shards, idx):
     """Issue the per-direction halo transfers for one streaming step.
 
@@ -188,8 +204,8 @@ def _halo_exchange_x(f, x_axes, n_shards, idx):
     The ring wraps because the global propagate is periodic -- edge shards
     exchange across the domain boundary, not zeros.
     """
-    plus_last = f[jnp.array(_PLUS_X)][:, -1:]      # (5, 1, Y, Z)
-    minus_first = f[jnp.array(_MINUS_X)][:, :1]    # (5, 1, Y, Z)
+    plus_last = _directions(f[:, -1:], _PLUS_X)      # (5, 1, Y, Z)
+    minus_first = _directions(f[:, :1], _MINUS_X)    # (5, 1, Y, Z)
     if len(x_axes) == 1:
         ax = x_axes[0]
         down = [(j, (j + 1) % n_shards) for j in range(n_shards)]
@@ -221,17 +237,20 @@ def _propagate_interior(f):
 
 def _propagate_boundary(f, halo_lo, halo_hi):
     """The two boundary planes of the pull propagate -- the only planes
-    that read the arriving halo slabs.  Valid for XL >= 2."""
+    that read the arriving halo slabs.  Valid for XL >= 2.  Planes 0, 1,
+    XL-2 and XL-1 are sliced out first: XLA would otherwise copy out each
+    direction's whole stripe to take its edge planes."""
+    head, tail = f[:, :2], f[:, -2:]
     lo = [None] * Q
     hi = [None] * Q
     for v in _ZERO_X:
-        lo[v] = _roll_yz(f[v][:1], v)
-        hi[v] = _roll_yz(f[v][-1:], v)
+        lo[v] = _roll_yz(head[v, :1], v)
+        hi[v] = _roll_yz(tail[v, 1:], v)
     for k, v in enumerate(_PLUS_X):
         lo[v] = _roll_yz(halo_lo[k], v)
-        hi[v] = _roll_yz(f[v][-2:-1], v)
+        hi[v] = _roll_yz(tail[v, :1], v)
     for k, v in enumerate(_MINUS_X):
-        lo[v] = _roll_yz(f[v][1:2], v)
+        lo[v] = _roll_yz(head[v, 1:], v)
         hi[v] = _roll_yz(halo_hi[k], v)
     return jnp.stack(lo, axis=0), jnp.stack(hi, axis=0)
 
@@ -271,25 +290,39 @@ def _collide_planes_planned(fprop, omega, layout: str):
 
 def _spmd_lbm_step(ctx, x_axes, f, layout, omega, mask):
     """Overlapped shard body shared by both layouts: issue the halo slabs,
-    propagate+collide the interior planes while they fly, then finish the
-    two boundary planes from the arrived slabs (docs/OVERLAP.md)."""
+    sweep the planes that need no remote data while they fly, then finish
+    the two boundary planes from the arrived slabs (docs/OVERLAP.md)."""
     n_shards = ctx.size(x_axes)
     if n_shards <= 1:
         # X whole on this shard (divisibility fallback or size-1 data
-        # axis): the single-device step on a locally planned block.
-        shape, dtype = _plan_args(f)
-        plan = dispatch.plan_for(f"lbm.{layout}", shape, dtype, local=True)
-        step = _step_soa if layout == "soa" else _step_ivjk
-        return step(f, omega, mask, plan=plan)
+        # axis): the single-device launch on a locally planned block.
+        plan = dispatch.plan_for(f"lbm.{layout}", tuple(f.shape), f.dtype,
+                                 local=True)
+        launch = _launch_soa if layout == "soa" else _launch_ivjk
+        return launch(plan, f, omega=omega, mask=mask)
     q, xl, y, z = f.shape
     idx = ctx.index(x_axes)
+    fused = layout == "ivjk" and _fused_path(
+        f.shape, f.dtype, masked=mask is not None, x_axes=x_axes)
+    # 1) issue the halo exchange for this step ...
+    halo_lo, halo_hi = _halo_exchange_x(f, x_axes, n_shards, idx)
+    if fused:
+        # 2) ... sweep the whole stripe while it is in flight: the kernel
+        # wraps X within the stripe, so every plane but 0 and XL-1 is
+        # final ...
+        out = kernel.pull_collide_ivjk(f, omega, ny=y, nz=z, soa_in=True,
+                                       soa_out=True)
+        # 3) ... and overwrite those two in place from the arrived slabs.
+        flo, fhi = _propagate_boundary(f, halo_lo, halo_hi)
+        out = jax.lax.dynamic_update_slice_in_dim(
+            out, _collide_planes(flo, omega), 0, axis=1)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, _collide_planes(fhi, omega), xl - 1, axis=1)
     # The mask rides along replicated (scalars close over the body); each
     # shard slices its own X planes.
     mask_l = None
     if mask is not None:
         mask_l = jax.lax.dynamic_slice_in_dim(mask, idx * xl, xl, axis=0)
-    # 1) issue the halo exchange for this step ...
-    halo_lo, halo_hi = _halo_exchange_x(f, x_axes, n_shards, idx)
     if xl > 2:
         # 2) ... propagate+collide the interior planes while it is in
         # flight (plan cell: the interior slab this shard actually sweeps)
@@ -383,17 +416,19 @@ def lbm_run(f: jax.Array, omega: float, iters: int, *,
     if layout not in LAYOUTS:
         raise ValueError(f"layout must be one of {LAYOUTS}")
     # Under an ambient multi-device mesh, route every step through the
-    # shard_map path (a pinned plan would force the single-device body);
+    # shard_map path (a pinned plan would force the single-device body),
+    # whose body chooses and reports its path from the local stripe;
     # consecutive steps pipeline -- step k+1's halo slabs fly while step
-    # k's interior planes are still colliding.
+    # k's interior planes are still colliding.  Two steps a loop body: a
+    # step writes a new stripe, so a one-step body must copy the whole
+    # stripe back into the loop's buffer, while two ping-pong between
+    # the loop's buffer and one other.
     if spmd_lib.spmd_mesh() is not None:
-        if layout == "ivjk":    # reported: the shard body runs unfused
-            _fused_path(f.shape, f.dtype, spmd=True)
         return jax.jit(
             lambda f0: jax.lax.fori_loop(
                 0, iters,
                 lambda _, x: dispatch.launch(f"lbm.{layout}", x,
-                                             omega=omega), f0,
+                                             omega=omega), f0, unroll=2,
             )
         )(f)
     if layout == "ivjk" and _fused_path(f.shape, f.dtype):

@@ -124,6 +124,75 @@ class TestJacobi:
             == pytest.approx(6.0)
 
 
+# The mesh cases of ``TestLBM.test_path_event_names_the_path``: a local
+# stripe, masked or not, and the mesh it is one of four stripes of, its X
+# over "data" (4x1) or over "data" and "model" (2x2); then the path and
+# reason its shard body reports.
+MESH_PATH_CASES = [
+    ((19, 4, 8, 128), False, "4x1", "fused", ""),
+    ((19, 4, 8, 8), False, "4x1", "unfused", "Z 8 not a multiple of 128"),
+    ((19, 2, 8, 128), False, "4x1", "unfused", "stripe under 3 planes"),
+    ((19, 4, 8, 128), True, "4x1", "unfused", "mask"),
+    ((19, 4, 8, 128), False, "2x2", "unfused", "multi-axis X"),
+]
+
+# Launches each case's whole lattice on four forced host devices and
+# prints the ``lbm_path`` events of each.
+MESH_PATHS = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json, sys
+import jax, jax.numpy as jnp, numpy as np
+from repro import api, obs
+from repro.kernels.lbm import ops
+from repro.parallel import rules
+
+out = []
+for shape, masked, mesh in json.loads(sys.argv[1]):
+    d, m = (int(n) for n in mesh.split("x"))
+    grid = jax.sharding.Mesh(np.asarray(jax.devices()).reshape(d, m),
+                             ("data", "model"))
+    table = rules.make_rules(
+        overrides={"batch": ("data", "model") if m > 1 else ("data",)})
+    glob = (shape[0], 4 * shape[1]) + tuple(shape[2:])
+    f = jax.ShapeDtypeStruct(glob, jnp.float32)
+    sink = obs.RingBufferSink(capacity=8)
+    with obs.session(sink), rules.use_rules(table, grid), \
+            api.plan_context(mesh=grid):
+        if masked:
+            mask = jax.ShapeDtypeStruct(glob[1:], jnp.bool_)
+            jax.eval_shape(lambda f, k: api.launch(
+                "lbm.ivjk", f, omega=1.2, mask=k), f, mask)
+        else:
+            jax.eval_shape(lambda f: ops.lbm_run(f, 1.2, 3), f)
+    out.append([[e.kernel, list(e.shape), e.dtype, e.path, e.reason]
+                for e in sink.events("lbm_path")])
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def lbm_mesh_paths():
+    """The ``lbm_path`` events of each of ``MESH_PATH_CASES``, from one
+    child process."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    cases = [[list(shape), masked, mesh]
+             for shape, masked, mesh, _, _ in MESH_PATH_CASES]
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(root, "src"))
+    env.pop("XLA_FLAGS", None)
+    res = subprocess.run([sys.executable, "-c", MESH_PATHS,
+                          json.dumps(cases)], capture_output=True, text=True,
+                         cwd=root, env=env, timeout=600)
+    assert res.returncode == 0, res.stderr[-3000:]
+    return json.loads(res.stdout.strip().splitlines()[-1])
+
+
 class TestLBM:
     @pytest.mark.parametrize("layout", ["soa", "ivjk"])
     @pytest.mark.parametrize("n", [8, 16])
@@ -210,17 +279,30 @@ class TestLBM:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=1e-7)
 
-    @pytest.mark.parametrize("shape,masked,path,reason", [
-        (FUSED[0], False, "fused", ""),
-        (FUSED[1], False, "fused", ""),
-        ((19, 8, 8, 8), False, "unfused", "Z 8 not a multiple of 128"),
-        (FUSED[0], True, "unfused", "mask"),
-    ], ids=["fused-128", "fused-256", "z8", "masked"])
-    def test_path_event_names_the_path(self, shape, masked, path, reason):
+    @pytest.mark.parametrize("shape,masked,path,reason,mesh_case", [
+        (FUSED[0], False, "fused", "", None),
+        (FUSED[1], False, "fused", "", None),
+        ((19, 8, 8, 8), False, "unfused", "Z 8 not a multiple of 128", None),
+        (FUSED[0], True, "unfused", "mask", None),
+    ] + [(shape, masked, path, reason, i)
+         for i, (shape, masked, _, path, reason)
+         in enumerate(MESH_PATH_CASES)],
+        ids=["fused-128", "fused-256", "z8", "masked", "mesh-fused",
+             "mesh-z8", "mesh-stripe2", "mesh-masked", "mesh-multi-axis"])
+    def test_path_event_names_the_path(self, shape, masked, path, reason,
+                                       mesh_case, request):
         """lbm_run, and a launch with a mask, report the path they take
-        (traced only: the choice is made from the shape)."""
+        (traced only: the choice is made from the shape).  Under a mesh
+        the shard body reports its local stripe (``shape`` here); those
+        cases (``mesh_case`` indexes ``MESH_PATH_CASES``) read
+        ``lbm_mesh_paths``."""
         from repro import obs
 
+        if mesh_case is not None:
+            events = request.getfixturevalue("lbm_mesh_paths")[mesh_case]
+            assert events == [["lbm.ivjk", list(shape), "float32", path,
+                               reason]]
+            return
         f = jax.ShapeDtypeStruct(shape, jnp.float32)
         sink = obs.RingBufferSink(capacity=8)
         with obs.session(sink):

@@ -128,6 +128,41 @@ def test_lbm_fused_run_compiles_at_cell_size(one_chip, native):
     assert not re.search(r"= f32\[[0-9,]+\]\S* (copy|transpose)\(", hlo)
 
 
+def test_lbm_sharded_run_compiles_at_cell_size(topo, native):
+    """The four-chip cell's call, 8 sweeps of (19, 2048, 256, 256) fp32
+    X-sharded on a 4x1 mesh: each shard sweeps its (19, 512, 256, 256)
+    stripe with the fused kernel and writes its two boundary planes in
+    place.  No stripe-sized copy, concatenate or slice is left around the
+    kernel (a one-sweep loop body copied the stripe back into the loop's
+    buffer every sweep), and the temporaries are one stripe."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from repro.kernels.lbm import kernel, ops
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(4, 1), ("data", "model"))
+    sharded = NamedSharding(mesh, PartitionSpec(None, "data", None, None))
+    f = jax.ShapeDtypeStruct((19, 2048, 256, 256), jnp.float32,
+                             sharding=sharded)
+    with api.plan_context(mesh=mesh):
+        compiled = jax.jit(lambda f: ops.lbm_run(f, 1.2, 8),
+                           donate_argnums=0).lower(f).compile()
+    hlo = compiled.as_text()
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    for line in calls:
+        assert re.match(rf"\s*(ROOT )?%{kernel.KERNEL_NAME}\.\d+ = ", line)
+    assert "collective-permute" in hlo
+    stripe = "f32[19,512,256,256]"
+    for line in hlo.splitlines():
+        if f"= {stripe}" in line:
+            assert not re.search(r" (copy|concatenate|slice|transpose)\(",
+                                 line), line
+    stripe_bytes = 19 * 512 * 256 * 256 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * stripe_bytes
+
+
 @pytest.fixture(scope="module")
 def qwen2():
     from repro.configs import get_config
